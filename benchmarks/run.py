@@ -30,13 +30,16 @@ def main() -> None:
     picks = [a for a in sys.argv[1:] if a in BENCHES] or list(BENCHES)
     print("name,value,derived")
     t0 = time.time()
-    results = {}
+    failed = []
     for name in picks:
         try:
-            results[name] = BENCHES[name]()
-        except Exception as e:  # report, keep going
+            BENCHES[name]()
+        except Exception as e:  # report, run the rest, then fail the run
             print(f"{name}_ERROR,1,{e!r}")
+            failed.append(name)
     print(f"total_wall_s,{time.time() - t0:.1f},{len(picks)} benches")
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,9 +1,11 @@
 """End-to-end driver: federated LM pretraining across 3 silos with UnifyFL.
 
-Each silo's clients train a decoder LM (reduced qwen3-family config on this
-CPU host; pass --preset full on a TPU pod for the real 1.7B) on the silo's
-own Markov-dialect token stream — the LM analogue of cross-silo NIID. Async
-mode, top-k policy, loss-based scoring. A few hundred client steps total.
+Each silo's clients train a decoder LM (the reduced qwen3-family smoke
+config, small enough for the CPU) on the silo's own Markov-dialect token
+stream — the LM analogue of cross-silo NIID. Async mode, top-k policy,
+loss-based scoring. A few hundred client steps total. The published widths
+run on a TPU through ``python chip_smoke.py`` (qwen3-1.7b cut to 4 layers:
+three silos of the full 28 layers do not fit one 16 GB chip).
 
   PYTHONPATH=src python examples/train_lm_federated.py
 """

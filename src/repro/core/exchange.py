@@ -5,7 +5,8 @@ score + policy-select + re-aggregate round becomes collectives over the
 ``pod`` axis, fused into one compiled program with the local train step:
 
   round_step(state, batch):
-    shard_map manual over 'pod' (auto over data/model):
+    shard_map manual over every mesh axis (a silo's block is replicated
+    over its own 'data' x 'model' devices):
       1. local train step (client SGD on the silo's batch)
       2. exchange:
          'all' policy  -> weighted psum over 'pod' (no gather, no scoring)
@@ -16,44 +17,35 @@ score + policy-select + re-aggregate round becomes collectives over the
                           matrix, collapse via the score policy, mask via the
                           aggregation policy, weighted-sum the gathered models.
 
-Used by launch/dryrun.py for the multi-pod mesh; the control-plane
+Used by launch/dryrun.py for the multi-pod mesh and by ``chip_smoke.py
+--chips 4`` on four one-chip silos; the control-plane
 (ledger+CAS) path in core/orchestrator.py is the faithful WAN variant.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import pshard
-from repro.config import ModelConfig
 from repro.models.api import Model
-
-try:
-    from jax import shard_map
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def _pod_manual_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map manual over 'pod' with the replication/VMA check off.
+    """shard_map manual over every mesh axis, replication check off.
 
-    jax >= 0.5 takes ``axis_names``/``check_vma`` and stays auto over the
-    other mesh axes. jax 0.4.x partial-auto shard_map miscompiles
-    differentiated scan bodies (XLA `IsManualSubgroup` CHECK), so there we go
-    fully manual: in_specs only split 'pod', leaving data/model replicated —
-    pod-axis collectives (the thing under test) are unchanged."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         axis_names={"pod"}, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    Inputs split along 'pod' only, so each silo's block is replicated over
+    its 'data' x 'model' devices and its step runs whole on each of them.
+    Leaving 'data'/'model' to the partitioner (partial-auto shard_map) makes
+    XLA's SPMD partitioner abort (``spmd_partitioner_util.cc`` replica-group
+    CHECK) once a batch constraint on 'data' meets a 'data' axis larger than
+    1; with every axis manual there is nothing left for it to partition."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 @dataclass(frozen=True)
@@ -148,15 +140,10 @@ def _sketch(params, dim: int):
     return acc / jnp.sqrt(jnp.float32(len(leaves)))
 
 
-def exchange(params, score_fn: Callable, score_batch, cfg: ExchangeConfig,
-             n_pods: Optional[int] = None):
-    """Inside shard_map manual over 'pod'. params: silo-local pytree.
-    score_fn(params, batch) -> scalar loss. Returns merged params.
-
-    ``n_pods`` is the static pod-axis size; callers that know their mesh pass
-    it (jax 0.4.x has no ``lax.axis_size`` to recover it in-trace)."""
-    n = n_pods if n_pods is not None \
-        else lax.axis_size("pod")  # jax >= 0.5 only
+def exchange(params, score_fn: Callable, score_batch, cfg: ExchangeConfig):
+    """Inside the pod shard_map region. params: silo-local pytree.
+    score_fn(params, batch) -> scalar loss. Returns merged params."""
+    n = lax.axis_size("pod")
     my_idx = lax.axis_index("pod")
     if cfg.policy == "self" or n == 1:
         return params
@@ -197,9 +184,12 @@ def exchange(params, score_fn: Callable, score_batch, cfg: ExchangeConfig,
         scores = _collapse_scores(mat, cfg.score_policy)
 
     w = _policy_weights(scores, my_idx, cfg, n)  # [n]
+    # one f32 term per model, summed in one fusion: no f32 copy of the
+    # gathered [n, ...] stack (that copy planned 12.3 GB of temporaries per
+    # v5e chip for four 516M-parameter silos; this sum plans 9.2 GB)
     merged = jax.tree.map(
-        lambda g, p: jnp.tensordot(w, g.astype(jnp.float32),
-                                   axes=([0], [0])).astype(p.dtype),
+        lambda g, p: sum(w[i] * g[i].astype(jnp.float32)
+                         for i in range(n)).astype(p.dtype),
         gathered, params)
     return merged
 
@@ -253,14 +243,13 @@ def make_unifyfl_round_step(model: Model, mesh, ex_cfg: ExchangeConfig,
     train_step = make_train_step(model, lr)
 
     def per_pod(params_blk, batch_blk):
-        with pshard.manual_axes(("pod",)):
+        with pshard.manual_axes(mesh.axis_names):
             params = jax.tree.map(lambda x: x[0], params_blk)
             batch = jax.tree.map(lambda x: x[0], batch_blk)
             new_params, metrics = train_step(params, batch)
             score_fn = lambda p, b: model.loss(p, b)[0]
             score_batch = jax.tree.map(lambda x: x[:ex_cfg.score_batch], batch)
-            merged = exchange(new_params, score_fn, score_batch, ex_cfg,
-                              n_pods=int(mesh.shape["pod"]))
+            merged = exchange(new_params, score_fn, score_batch, ex_cfg)
             out = jax.tree.map(lambda x: x[None], merged)
             loss = metrics["loss"][None]
         return out, loss
@@ -279,7 +268,7 @@ def make_pod_serve_step(model: Model, mesh, kind: str):
     collectives; proves pod-axis sharding coherence for serve shapes)."""
 
     def per_pod_decode(params_blk, batch_blk, cache_blk):
-        with pshard.manual_axes(("pod",)):
+        with pshard.manual_axes(mesh.axis_names):
             params = jax.tree.map(lambda x: x[0], params_blk)
             batch = jax.tree.map(lambda x: x[0] if x.ndim > 0 else x, batch_blk)
             cache = jax.tree.map(lambda x: x[0], cache_blk)
@@ -288,7 +277,7 @@ def make_pod_serve_step(model: Model, mesh, kind: str):
                     jax.tree.map(lambda x: x[None], cache))
 
     def per_pod_prefill(params_blk, batch_blk):
-        with pshard.manual_axes(("pod",)):
+        with pshard.manual_axes(mesh.axis_names):
             params = jax.tree.map(lambda x: x[0], params_blk)
             batch = jax.tree.map(lambda x: x[0], batch_blk)
             logits, cache = model.prefill(params, batch)

@@ -216,8 +216,7 @@ class SiloRuntime:
         for c in picked:  # may hit IPFS peers over the fabric
             try:
                 dm = self.get_decoded(c.cid)
-                if dm.needs_base:
-                    dm.vec()  # resolve the delta base chain (may fetch)
+                dm.resolve_base()  # resolve the delta base chain (may fetch)
                 peers.append(dm)
             except (KeyError, IOError):
                 self.env.emit(obsev.pull_fail(self.silo_id, c.cid))
@@ -350,8 +349,7 @@ class SiloRuntime:
         for cid in cids:
             try:
                 dm = self.get_decoded(cid)
-                if dm.needs_base:
-                    dm.vec()  # resolve (and, for deltas, fetch) the base now
+                dm.resolve_base()  # fetch a delta's base chain now
                 decoded.append(dm)
                 kept.append(cid)
             except (KeyError, IOError):
@@ -787,8 +785,7 @@ class SyncOrchestrator(BaseOrchestrator):
         for e in entries:
             try:
                 dm = silo0.get_decoded(e.cid)
-                if dm.needs_base:
-                    dm.vec()  # resolve the delta base chain (may fetch)
+                dm.resolve_base()  # resolve the delta base chain (may fetch)
                 decoded.append(dm)
                 reachable.append(e)
             except (KeyError, IOError):

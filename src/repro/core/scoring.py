@@ -46,8 +46,8 @@ def multikrum_scores_for_decoded(decoded: Sequence, m: int) -> List[float]:
     [M] score vector crosses to the host exactly once."""
     if (all(d.is_q8 for d in decoded)
             and len({int(d.q.shape[0]) for d in decoded}) == 1):
-        q = jnp.stack([d.q for d in decoded])
-        s = jnp.stack([d.scales for d in decoded])
+        q = np.stack([np.asarray(d.q) for d in decoded])
+        s = np.stack([np.asarray(d.scales) for d in decoded])
         scores = ops.multikrum_scores_q8(q, s, m)
     else:
         from repro.fed.scorebatch import stack_decoded_vecs

@@ -42,6 +42,11 @@ from repro.kernels import ops
 WIRE_VERSION = 1
 METHODS = ("raw", "int8", "int8-delta", "topk-delta")
 QT = ops.QTILE                 # quantization tile (scale granularity)
+# DecodedModel.vec() keeps its reconstruction only up to this size. A model
+# of 516M parameters reconstructs to 2 GB of f32 on the device; kept per
+# decoded CID in every store node's cache, those vectors fill a 16 GB chip
+# within two rounds. Larger models reconstruct again on each use instead.
+VEC_MEMO_MAX_BYTES = 256 << 20
 
 # Exact keystr paths of envelope fields as serialized by store.serialize_pytree
 # (exact-match lookups: substring matching broke on params literally named "q").
@@ -138,18 +143,24 @@ class ModelEnvelope:
         if self.method == "topk-delta":
             return base.at[jnp.asarray(self.idx)].add(
                 jnp.asarray(self.vals, jnp.float32))
-        # int8-delta: scatter the kept tiles into the dense quant grid, then
-        # one fused base + s*q pass (no f32 delta is ever materialized)
-        tiles = jnp.asarray(self.tiles)
-        T = int(tiles.shape[0])
-        if T == 0:
+        # int8-delta: one fused base + s*q pass (no f32 delta is ever
+        # materialized)
+        if np.asarray(self.tiles).shape[0] == 0:
             return base
-        total = _padded_n(n) // QT
-        qd = jnp.zeros((total, QT), jnp.int8).at[tiles].set(
-            jnp.asarray(self.q).reshape(T, QT))
-        sd = jnp.zeros((total,), jnp.float32).at[tiles].set(
-            jnp.asarray(self.scales))
-        return ops.add_q8_delta(base, qd.reshape(-1), sd, n, force=force)
+        qd, sd = dense_q8_delta(self.tiles, self.q, self.scales, n)
+        return ops.add_q8_delta(base, qd, sd, n, force=force)
+
+
+def dense_q8_delta(tiles, q, scales, n: int):
+    """Scatter an int8-delta's kept tiles into the dense quant grid of an
+    n-vector, on the host (the payload arrived there): (q int8 [Np],
+    scales f32 [Np/QT]), zero in the tiles that were dropped."""
+    total = _padded_n(n) // QT
+    qd = np.zeros((total, QT), np.int8)
+    qd[np.asarray(tiles)] = np.asarray(q).reshape(-1, QT)
+    sd = np.zeros((total,), np.float32)
+    sd[np.asarray(tiles)] = np.asarray(scales)
+    return qd.reshape(-1), sd
 
 
 # --------------------------------------------------------------------------- #
@@ -254,9 +265,11 @@ def base_cid_of_store(flat: Dict) -> str:
 class DecodedModel:
     """A peer model decoded from its wire envelope, kept in exchange form.
 
-    Quantized payloads stay as (q int8, scales) so the fused kernels consume
-    them without ever materializing the f32 vector; ``vec()`` reconstructs
-    lazily and memoizes. Delta envelopes resolve their base chain through
+    Quantized payloads stay as (q int8, scales) host arrays, so a store
+    node's decoded cache holds no device memory and the fused kernels
+    consume them without ever materializing the f32 vector; ``vec()``
+    reconstructs lazily and memoizes up to ``VEC_MEMO_MAX_BYTES``. Delta
+    envelopes resolve their base chain through
     ``resolver`` (the store node's decoded cache, which fetches missing base
     CIDs over the fabric), then apply the int8 delta with the fused
     ``add_q8_delta`` kernel."""
@@ -287,9 +300,17 @@ class DecodedModel:
         Gram kernels (delta payloads must reconstruct first)."""
         return self.method == "int8" and self.q is not None
 
-    @property
-    def needs_base(self) -> bool:
-        return bool(self.base_cid) and self._vec is None
+    def resolve_base(self) -> None:
+        """Fetch and decode the delta base chain without reconstructing
+        anything, so an unreachable base surfaces now (KeyError/IOError)."""
+        if self.base_cid and self._vec is None:
+            self._base().resolve_base()
+
+    def _base(self) -> "DecodedModel":
+        if self._resolver is None:
+            raise KeyError(f"delta base {self.base_cid} needs a "
+                           "store-bound resolver to reconstruct")
+        return self._resolver(self.base_cid)
 
     def _envelope(self) -> ModelEnvelope:
         return ModelEnvelope(self.method, self.n, base_cid=self.base_cid,
@@ -297,18 +318,17 @@ class DecodedModel:
                              idx=self.idx, vals=self.vals, vec=self._vec)
 
     def vec(self):
-        """Flat f32 [n] view of the model (reconstructed once, then cached).
-        Delta models resolve ``base_cid`` recursively through the resolver;
-        a missing base without a resolver is an error."""
-        if self._vec is None:
-            base = None
-            if self.base_cid:
-                if self._resolver is None:
-                    raise KeyError(f"delta base {self.base_cid} needs a "
-                                   "store-bound resolver to reconstruct")
-                base = self._resolver(self.base_cid).vec()
-            self._vec = self._envelope().reconstruct(base)
-        return self._vec
+        """Flat f32 [n] view of the model (reconstructed once, then cached
+        when it is at most ``VEC_MEMO_MAX_BYTES``). Delta models resolve
+        ``base_cid`` recursively through the resolver; a missing base
+        without a resolver is an error."""
+        if self._vec is not None:
+            return self._vec
+        base = self._base().vec() if self.base_cid else None
+        vec = self._envelope().reconstruct(base)
+        if 4 * self.n <= VEC_MEMO_MAX_BYTES:
+            self._vec = vec
+        return vec
 
 
 def decode_store(flat: Dict[str, np.ndarray],
@@ -326,7 +346,7 @@ def decode_store(flat: Dict[str, np.ndarray],
         method = str(np.asarray(flat[K_METHOD]))
         n = int(np.asarray(flat[K_N]))
         base_cid = str(np.asarray(flat[K_BASE])) if K_BASE in flat else ""
-        j = lambda key: jnp.asarray(flat[key]) if key in flat else None
+        j = lambda key: np.asarray(flat[key]) if key in flat else None
         if method == "raw":
             return DecodedModel(n, vec=jnp.asarray(flat[K_VEC], jnp.float32),
                                 method="raw")
@@ -345,8 +365,8 @@ def decode_store(flat: Dict[str, np.ndarray],
     legacy = flat.get(K_METHOD)
     if legacy is not None and str(np.asarray(legacy)) == "int8":
         return DecodedModel(int(np.asarray(flat[K_N])),
-                            q=jnp.asarray(flat[K_Q]),
-                            scales=jnp.asarray(flat[K_SCALES]))
+                            q=np.asarray(flat[K_Q]),
+                            scales=np.asarray(flat[K_SCALES]))
     if not flat:
         return DecodedModel(0, vec=jnp.zeros((0,), jnp.float32))
     vec = jnp.concatenate([jnp.ravel(jnp.asarray(v)).astype(jnp.float32)

@@ -34,16 +34,25 @@ def make_image_dataset(n_classes: int = 10, n_train: int = 6000,
             "n_classes": n_classes}
 
 
+MARKOV_STATES = 256  # distinct successor distributions in an LM stream
+
+
 def make_lm_dataset(vocab: int = 256, length: int = 200_000, *,
                     n_dialects: int = 1, dialect_strength: float = 0.5,
                     seed: int = 0) -> List[np.ndarray]:
     """Markov token streams, one per dialect. Shared base transition matrix
-    plus per-dialect sparse perturbation => silo data is NIID but related."""
+    plus per-dialect sparse perturbation => silo data is NIID but related.
+
+    Token t draws its successor from row ``t % MARKOV_STATES``, so the
+    matrices hold MARKOV_STATES x vocab probabilities: a dense vocab x vocab
+    one is 185 GB at a 151,936-token vocabulary. A vocabulary no larger than
+    MARKOV_STATES keeps one row per token."""
     rng = np.random.default_rng(seed)
-    base = rng.dirichlet(np.ones(vocab) * 0.3, size=vocab)
+    states = min(vocab, MARKOV_STATES)
+    base = rng.dirichlet(np.ones(vocab) * 0.3, size=states)
     streams = []
     for d in range(n_dialects):
-        pert = rng.dirichlet(np.ones(vocab) * 0.1, size=vocab)
+        pert = rng.dirichlet(np.ones(vocab) * 0.1, size=states)
         trans = (1 - dialect_strength) * base + dialect_strength * pert
         trans = trans / trans.sum(axis=1, keepdims=True)
         cum = np.cumsum(trans, axis=1)
@@ -51,7 +60,7 @@ def make_lm_dataset(vocab: int = 256, length: int = 200_000, *,
         t = rng.integers(0, vocab)
         u = rng.random(length)
         for i in range(length):
-            t = int(np.searchsorted(cum[t], u[i]))
+            t = int(np.searchsorted(cum[t % states], u[i]))
             t = min(t, vocab - 1)
             toks[i] = t
         streams.append(toks)

@@ -18,7 +18,11 @@ from repro.optim.fedopt import ServerOptimizer, make_server_optimizer
 
 
 def fedavg_params(params_list: Sequence, weights: Sequence[float]):
-    """Sample-count-weighted average of parameter pytrees (kernel-backed)."""
+    """Sample-count-weighted average of parameter pytrees (kernel-backed).
+    One client's average is its own params: weight 1.0 is exact, so the
+    flatten / sum / unflatten round trip is skipped."""
+    if len(params_list) == 1:
+        return params_list[0]
     w = np.asarray(weights, np.float64)
     w = (w / w.sum()).astype(np.float32)
     vecs, spec = ops.flatten_batch(params_list)
@@ -62,8 +66,9 @@ class SiloAggregator:
             else:
                 f32_peers.append((wi, p))
         for grp in groups.values():
-            q = jnp.stack([p.q for _, p in grp])
-            s = jnp.stack([p.scales for _, p in grp])
+            # payloads arrive as host arrays: stack there, move once
+            q = np.stack([np.asarray(p.q) for _, p in grp])
+            s = np.stack([np.asarray(p.scales) for _, p in grp])
             gw = jnp.asarray(np.asarray([wi for wi, _ in grp], np.float32))
             mixed = mixed + ops.weighted_sum_q8(q, s, gw, n)
         for wi, p in f32_peers:

@@ -30,6 +30,7 @@ self-eval either).
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Dict, List, Sequence
 
@@ -75,8 +76,8 @@ def stack_decoded_vecs(decoded: Sequence, n: int):
         else:
             rows[i] = jnp.asarray(d.vec(), jnp.float32)[:n]
     for idxs in groups.values():
-        q = jnp.stack([decoded[i].q for i in idxs])
-        s = jnp.stack([decoded[i].scales for i in idxs])
+        q = np.stack([np.asarray(decoded[i].q) for i in idxs])
+        s = np.stack([np.asarray(decoded[i].scales) for i in idxs])
         mat = ops.dequantize_batch(q, s, n)
         if len(idxs) == K:  # uniform int8 round (the default compression's
             return mat      # hot path): the batch IS the answer, no restack
@@ -85,10 +86,52 @@ def stack_decoded_vecs(decoded: Sequence, n: int):
     return jnp.stack(rows)
 
 
+def _leaf_dtype(spec):
+    """The one dtype every leaf of ``spec`` has, else f32."""
+    dtypes = {dt for _, dt in spec[1]}
+    return dtypes.pop() if len(dtypes) == 1 else jnp.float32
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _dequantize_stacked(q, scales, spec):
+    # one leaf dtype: dequantize straight into it (the kernel rounds its f32
+    # product once, as the cast in unflatten_batch would) at half the bytes
+    return ops.unflatten_batch(
+        ops.dequantize_batch(q, scales, ops.spec_length(spec),
+                             _leaf_dtype(spec)), spec)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _stack_rows(rows, spec):
+    """K flat [n] vectors -> stacked pytree, stacking leaf by leaf."""
+    treedef, shapes = spec
+    leaves, off = [], 0
+    for shape, dtype in shapes:
+        n = int(np.prod(shape)) if shape else 1
+        leaves.append(jnp.stack([r[off:off + n].reshape(shape).astype(dtype)
+                                 for r in rows]))
+        off += n
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 def stack_decoded(decoded: Sequence, spec):
-    """Wire payloads -> stacked parameter pytree (leaves [K, *shape])."""
+    """Wire payloads -> stacked parameter pytree (leaves [K, *shape]).
+
+    One jitted program runs from the payloads to the stacked leaves, so no
+    f32 [K, N] matrix is left beside them (4 GB for two 516M-parameter
+    models): a uniform int8 round dequantizes inside it, and any other
+    round reconstructs each model's vector, casts it to the leaf dtype
+    before the next one is built (a cast per element, so the result is the
+    same) and stacks them inside it."""
+    if (decoded and all(getattr(d, "is_q8", False) for d in decoded)
+            and len({int(d.q.shape[0]) for d in decoded}) == 1):
+        q = np.stack([np.asarray(d.q) for d in decoded])
+        s = np.stack([np.asarray(d.scales) for d in decoded])
+        return _dequantize_stacked(q, s, spec)
     n = ops.spec_length(spec)
-    return ops.unflatten_batch(stack_decoded_vecs(decoded, n), spec)
+    dtype = _leaf_dtype(spec)
+    return _stack_rows(tuple(jnp.asarray(d.vec(), jnp.float32)[:n].astype(dtype)
+                             for d in decoded), spec)
 
 
 # --------------------------------------------------------------------------- #

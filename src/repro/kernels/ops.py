@@ -1,9 +1,14 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On TPU the pallas path compiles natively; elsewhere (this CPU container) the
-same kernel body runs under ``interpret=True`` so numerics are identical and
-every kernel is exercised end-to-end. ``force='ref'`` selects the pure-jnp
-oracle (used by tests to cross-validate).
+On TPU the pallas path compiles natively; elsewhere (the CPU, where the
+tests run) the same kernel body runs under ``interpret=True`` so numerics
+are identical and every kernel is exercised end-to-end. ``force='ref'``
+selects the pure-jnp oracle (used by tests to cross-validate).
+
+Each wrapper jits its padding, kernel call and trim as one program. The q8
+kernels take the f32 side unpadded (``kernels/quant.py``), so for a vector
+whose length is a multiple of 128 the pad and the trim are no-ops and no
+padded copy of a model-sized vector is made.
 """
 from __future__ import annotations
 
@@ -50,7 +55,7 @@ def make_flatten_spec(params):
     key = (treedef, tuple((tuple(l.shape), np.dtype(l.dtype)) for l in leaves))
     spec = _SPEC_CACHE.get(key)
     if spec is None:
-        spec = (treedef, [(l.shape, l.dtype) for l in leaves])
+        spec = (treedef, key[1])  # hashable: a static argument of jit
         _SPEC_CACHE[key] = spec
     return spec
 
@@ -116,14 +121,18 @@ def spec_length(spec) -> int:
 # MultiKRUM
 # --------------------------------------------------------------------------- #
 
+@jax.jit
+def _pairwise_dists(x):
+    g, sq = _mk.gram_and_norms(_pad_to(x, 1, _mk.TILE_N),
+                               interpret=_interpret())
+    return jnp.maximum(sq + sq.T - 2.0 * g, 0.0)
+
+
 def pairwise_dists(x, force: str = "auto"):
     """x: [M, N] -> pairwise squared L2 [M, M]."""
     if force == "ref":
         return _ref.multikrum_dists(x)
-    xp = _pad_to(x, 1, _mk.TILE_N)
-    g, sq = _mk.gram_and_norms(xp, interpret=_interpret())
-    d = sq + sq.T - 2.0 * g
-    return jnp.maximum(d, 0.0)
+    return _pairwise_dists(x)
 
 
 def multikrum_scores(x, m: int, force: str = "auto"):
@@ -141,13 +150,17 @@ def multikrum_scores(x, m: int, force: str = "auto"):
 # Weighted aggregation
 # --------------------------------------------------------------------------- #
 
+@jax.jit
+def _weighted_sum(x, w):
+    xp = _pad_to(x, 1, _ws.TILE_N)
+    return _ws.weighted_sum(xp, w, interpret=_interpret())[:x.shape[1]]
+
+
 def weighted_sum(x, w, force: str = "auto"):
     """x: [M, N], w: [M] -> [N]."""
     if force == "ref":
         return _ref.weighted_sum(x, w)
-    N = x.shape[1]
-    xp = _pad_to(x, 1, _ws.TILE_N)
-    return _ws.weighted_sum(xp, w, interpret=_interpret())[:N]
+    return _weighted_sum(x, w)
 
 
 # --------------------------------------------------------------------------- #
@@ -164,6 +177,16 @@ def _pad_q8(q, scales):
             _pad_to(scales, scales.ndim - 1, _q8.QPB))
 
 
+def _up128(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _wsum_q8(q, scales, w, n: int):
+    qp, sp = _pad_q8(q, scales)
+    return _q8.wsum_q8(qp, sp, w, _up128(n), interpret=_interpret())[:n]
+
+
 def weighted_sum_q8(q, scales, w, n: int = None, force: str = "auto"):
     """Fused dequantize + weighted sum. q: [M, Np] int8 (Np % QTILE == 0),
     scales: [M, Np/QTILE], w: [M] -> [n] f32 (n defaults to Np)."""
@@ -172,8 +195,15 @@ def weighted_sum_q8(q, scales, w, n: int = None, force: str = "auto"):
     n = Np if n is None else n
     if force == "ref":
         return _ref.wsum_q8(q, scales, w, QTILE)[:n]
-    qp, sp = _pad_q8(q, scales)
-    return _q8.wsum_q8(qp, sp, w, interpret=_interpret())[:n]
+    return _wsum_q8(q, scales, w, n)
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _add_q8_delta(base, q, scales, n: int):
+    qp = _pad_to(q, 0, QUANT_BLOCK)
+    sp = _pad_to(scales, 0, QUANT_BLOCK // QTILE)
+    bp = _pad_to(base[:n].astype(jnp.float32), 0, 128)
+    return _q8.add_q8_delta(bp, qp, sp, interpret=_interpret())[:n]
 
 
 def add_q8_delta(base, q, scales, n: int = None, force: str = "auto"):
@@ -184,21 +214,21 @@ def add_q8_delta(base, q, scales, n: int = None, force: str = "auto"):
     assert q.shape[0] % QTILE == 0, f"delta payload must be {QTILE}-aligned"
     if force == "ref":
         return _ref.add_q8_delta(base[:n], q, scales, QTILE)
-    qp = _pad_to(q, 0, QUANT_BLOCK)
-    sp = _pad_to(scales, 0, QUANT_BLOCK // QTILE)
-    bp = jnp.pad(base[:n].astype(jnp.float32), (0, qp.shape[0] - n))
-    return _q8.add_q8_delta(bp, qp, sp, interpret=_interpret())[:n]
+    return _add_q8_delta(base, q, scales, n)
+
+
+@jax.jit
+def _pairwise_dists_q8(q, scales):
+    g, sq = _q8.gram_q8(*_pad_q8(q, scales), interpret=_interpret())
+    return jnp.maximum(sq + sq.T - 2.0 * g, 0.0)
 
 
 def pairwise_dists_q8(q, scales, force: str = "auto"):
     """Fused dequantize + pairwise squared L2 of quantized models [M, M]."""
     if force == "ref":
         g, sq = _ref.gram_q8(q, scales, QTILE)
-    else:
-        qp, sp = _pad_q8(q, scales)
-        g, sq = _q8.gram_q8(qp, sp, interpret=_interpret())
-    d = sq + sq.T - 2.0 * g
-    return jnp.maximum(d, 0.0)
+        return jnp.maximum(sq + sq.T - 2.0 * g, 0.0)
+    return _pairwise_dists_q8(q, scales)
 
 
 def multikrum_scores_q8(q, scales, m: int, force: str = "auto"):
@@ -214,7 +244,12 @@ def multikrum_scores_q8(q, scales, m: int, force: str = "auto"):
 # int8 compression
 # --------------------------------------------------------------------------- #
 
-QUANT_BLOCK = _q.TILE * _q.LANE
+QUANT_BLOCK = _q.BLOCK
+
+
+@jax.jit
+def _quantize(x):
+    return _q.quantize(_pad_to(x, 0, 128), interpret=_interpret())
 
 
 def quantize(x, force: str = "auto"):
@@ -224,15 +259,20 @@ def quantize(x, force: str = "auto"):
         xp = _pad_to(x, 0, _q.TILE)
         q, s = _ref.quantize_int8(xp, _q.TILE)
         return q, s, N
-    xp = _pad_to(x, 0, QUANT_BLOCK)
-    q, s = _q.quantize(xp, interpret=_interpret())
+    q, s = _quantize(x)
     return q, s, N
+
+
+@functools.partial(jax.jit, static_argnames=("n", "dtype"))
+def _dequantize(q, scales, n: int, dtype):
+    return _q.dequantize(q, scales, _up128(n), dtype=dtype,
+                         interpret=_interpret())[:n]
 
 
 def dequantize(q, scales, n, dtype=jnp.float32, force: str = "auto"):
     if force == "ref":
         return _ref.dequantize_int8(q, scales, _q.TILE)[:n].astype(dtype)
-    return _q.dequantize(q, scales, dtype=dtype, interpret=_interpret())[:n]
+    return _dequantize(q, scales, int(n), dtype)
 
 
 def dequantize_batch(q, scales, n, dtype=jnp.float32, force: str = "auto"):
@@ -242,6 +282,11 @@ def dequantize_batch(q, scales, n, dtype=jnp.float32, force: str = "auto"):
     matrix without K per-model dequant dispatches."""
     if force == "ref":
         return _ref.dequantize_rows(q, scales, _q.TILE)[:, :n].astype(dtype)
+    return _dequantize_batch(q, scales, int(n), dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "dtype"))
+def _dequantize_batch(q, scales, n: int, dtype):
     return _q.dequantize_batch(q, scales, dtype=dtype,
                                interpret=_interpret())[:, :n]
 

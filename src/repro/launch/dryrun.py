@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST run before any jax import: jax locks the device count on first init.
-# The dry-run (and only the dry-run) builds the 512-chip production meshes.
+# The dry-run (and only the dry-run) builds the 512-chip production meshes,
+# on fake CPU devices: it never takes an accelerator, and neither do the
+# --subprocess children, which inherit this environment.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

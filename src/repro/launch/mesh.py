@@ -22,10 +22,5 @@ def make_production_mesh(*, multi_pod: bool = False,
     else:
         shape = shape or (16, 16)
         axes = ("data", "model")
-    # jax < 0.5 has neither jax.sharding.AxisType nor the axis_types kwarg;
-    # Auto is the default there, so only pass it when the API exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(shape))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

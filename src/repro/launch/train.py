@@ -3,9 +3,14 @@
 Two modes:
   - image: the paper's CIFAR-like workload (CNN, Dirichlet-NIID silos)
   - lm:    federated LM pretraining over per-silo Markov dialects, for any
-           assigned architecture via --arch (reduced preset trains a small
-           same-family config on this CPU host; full preset is the real
-           config for TPU pods).
+           assigned architecture via --arch (the smoke preset trains a small
+           same-family config; the full preset is the published config,
+           which every silo holds whole on one device: qwen3-1.7b at its 28
+           layers does not fit three silos on one 16 GB chip, while
+           chip_smoke.py runs it at 4 layers).
+
+The persistent compile cache is on: ``JAX_COMPILATION_CACHE_DIR`` when set,
+else ``.jax_cache/`` in the checkout (repro.launch.cache).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --workload image --mode sync \
@@ -19,11 +24,11 @@ import argparse
 import json
 import time
 
-from repro.config import FedConfig, replace
+from repro.config import FedConfig
 from repro.configs import get_config, get_smoke_config
-from repro.core.builder import (SiloSpec, build_image_experiment,
-                                build_lm_experiment, global_eval)
-from repro.core.orchestrator import SiloPolicy
+from repro.core.builder import (build_image_experiment, build_lm_experiment,
+                                global_eval)
+from repro.launch.cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -48,6 +53,7 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--out", default=None)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     fed = FedConfig(n_silos=args.silos, clients_per_silo=args.clients,
                     rounds=args.rounds, local_epochs=args.local_epochs,

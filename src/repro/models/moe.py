@@ -16,22 +16,16 @@ through unchanged.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import pshard
 from repro.config import ModelConfig
 from repro.models import layers as L
 
-try:  # JAX >= 0.6 moved shard_map to jax.shard_map
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def init_moe(key, cfg: ModelConfig):
@@ -130,7 +124,9 @@ def moe_block(p, x, cfg: ModelConfig):
     B, S, D = x.shape
     mesh = pshard.get_mesh()
     m = cfg.moe
-    if mesh is None or "model" not in mesh.axis_names:
+    if mesh is None or "model" not in mesh.axis_names or pshard._MANUAL:
+        # no model axis, or inside a shard_map region (every axis manual):
+        # this device holds and runs every expert
         out, aux = _moe_local(p, x.reshape(-1, D), cfg, e_lo=0, e_hi=m.n_experts)
         return out.reshape(B, S, D), aux
 
@@ -176,13 +172,9 @@ def moe_block(p, x, cfg: ModelConfig):
                                    if a in mesh.axis_names))
         return out.reshape(x_l.shape), aux
 
-    # when nested inside a pod-manual shard_map (multi-pod round step), the
-    # inner shard_map must use the manual-typed abstract mesh and only claim
-    # the still-auto axes
-    smesh = pshard._constraint_mesh() if pshard._MANUAL else mesh
     names = {a for a in ("data", "model") if a in mesh.axis_names}
     blk_idx = jnp.arange(n_model, dtype=jnp.int32)
-    out, aux = shard_map(fn, mesh=smesh,
+    out, aux = shard_map(fn, mesh=mesh,
                          in_specs=(w_spec, x_spec, P("model")),
                          out_specs=(x_spec, P()), axis_names=names,
                          check_vma=False)(p, x, blk_idx)

@@ -25,14 +25,18 @@ def sgd(momentum: float = 0.0, weight_decay: float = 0.0) -> Optimizer:
             return ()
         return jax.tree.map(jnp.zeros_like, params)
 
+    # params keep their dtype: an f32 ``lr`` would otherwise promote bf16
+    # params to f32 on the first step, doubling every silo's resident model
     def update(grads, state, params, lr):
         if weight_decay:
             grads = jax.tree.map(lambda g, p: g + weight_decay * p, grads, params)
         if momentum == 0.0:
-            new_params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+            new_params = jax.tree.map(lambda p, g: (p - lr * g).astype(p.dtype),
+                                      params, grads)
             return new_params, ()
         new_m = jax.tree.map(lambda m, g: momentum * m + g, state, grads)
-        new_params = jax.tree.map(lambda p, m: p - lr * m, params, new_m)
+        new_params = jax.tree.map(lambda p, m: (p - lr * m).astype(p.dtype),
+                                  params, new_m)
         return new_params, new_m
 
     return Optimizer(init, update)

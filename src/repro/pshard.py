@@ -17,12 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _MESH: Optional[Mesh] = None
 _MANUAL: tuple = ()  # axes currently inside a shard_map manual region
 
-# jax < 0.5 can't express "Manual subgroup" constraint meshes (no AxisType);
-# emitting constraints inside a partial-manual shard_map region there trips
-# an XLA CHECK (IsManualSubgroup). Constraints are layout hints, so they are
-# simply skipped in manual regions on those versions.
-_HAS_AXISTYPE = hasattr(jax.sharding, "AxisType")
-
 # Logical batch axis: models constrain batch dims with the BATCH sentinel;
 # 'tp' sharding resolves it to ('pod','data'), 'fsdp' to
 # ('pod','data','model') (pure ZeRO-3: both axes act data-parallel).
@@ -46,7 +40,9 @@ def set_mesh(mesh: Optional[Mesh]) -> None:
 
 @contextlib.contextmanager
 def manual_axes(axes):
-    """Mark mesh axes as shard_map-manual: constraints drop them."""
+    """Mark mesh axes as shard_map-manual. The pod regions are manual over
+    every axis, so inside one ``constrain`` and ``param_shardings`` are
+    no-ops."""
     global _MANUAL
     prev, _MANUAL = _MANUAL, tuple(axes)
     try:
@@ -101,19 +97,6 @@ def resolve_spec(*spec) -> P:
     return P(*out)
 
 
-def _constraint_mesh():
-    """Inside a shard_map manual region the constraint's mesh must carry the
-    Manual axis types (JAX validates context mesh == sharding mesh)."""
-    if not _MANUAL:
-        return _MESH
-    try:
-        from jax.sharding import AxisType
-        return _MESH.abstract_mesh.update_axis_types(
-            {a: AxisType.Manual for a in _MANUAL if a in _MESH.axis_names})
-    except Exception:
-        return _MESH
-
-
 def _axis_size(ax) -> int:
     if ax is None or _MESH is None:
         return 1
@@ -139,14 +122,12 @@ def size_filter(spec: P, shape) -> P:
 
 
 def constrain(x, *spec):
-    """with_sharding_constraint against the installed mesh (no-op if none)."""
-    if _MESH is None or len(_MESH.axis_names) == 0:
-        return x
-    if _MANUAL and not _HAS_AXISTYPE:
+    """with_sharding_constraint against the installed mesh (no-op if none,
+    and inside a shard_map region, where every mesh axis is manual)."""
+    if _MESH is None or len(_MESH.axis_names) == 0 or _MANUAL:
         return x
     resolved = size_filter(resolve_spec(*spec), x.shape)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(_constraint_mesh(), resolved))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(_MESH, resolved))
 
 
 def named_sharding(*spec) -> Optional[NamedSharding]:
@@ -189,7 +170,7 @@ def tree_path_str(path) -> str:
 def param_shardings(params, rules):
     """Pytree of NamedSharding for a param pytree, by path-regex rules."""
     def one(path, leaf):
-        if _MESH is None or (_MANUAL and not _HAS_AXISTYPE):
+        if _MESH is None or _MANUAL:
             return None
         spec = spec_for_param(tree_path_str(path), leaf.shape, rules)
         return NamedSharding(_MESH, spec)
