@@ -341,6 +341,11 @@ class ObsConfig:
     trace_path: str = ""
     # include a flat metrics-registry snapshot in every round_log mark
     metrics_in_round_log: bool = True
+    # the program's wall-clock spans and counters (repro.obs.wall): host
+    # seconds, syncs and H2D bytes where the work happens, each span also a
+    # profiler annotation. Process-wide, and apart from ``enabled``, which
+    # starts the simulated tracer
+    wall: bool = False
 
 
 @dataclass(frozen=True)

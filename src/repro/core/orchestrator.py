@@ -45,7 +45,7 @@ from repro.core.store import StoreNetwork, StoreNode
 from repro.fed import scorebatch
 from repro.fed.cluster import Cluster
 from repro.kernels import ops
-from repro.obs import Observability, events as obsev
+from repro.obs import Observability, events as obsev, wall
 
 
 @dataclass
@@ -131,8 +131,10 @@ class SiloRuntime:
         non-retried reverts are traced and dropped (the paper's 'blockchain
         will no longer accept' semantics, seen from the client side)."""
         try:
-            return self.ledger.submit(self.silo_id, method,
-                                      logical_time=self.env.now, **args)
+            with wall.span("chain.submit", round=self.rounds_done + 1,
+                           silo=self.silo_id):
+                return self.ledger.submit(self.silo_id, method,
+                                          logical_time=self.env.now, **args)
         except PermissionError:
             if _retries > 0 and self.alive:
                 self.env.schedule(
@@ -182,6 +184,16 @@ class SiloRuntime:
         delta envelopes wired to resolve their base chain through the store."""
         return self.store.get_decoded(cid, self.store.wire_decoder())
 
+    def fetch(self, cid: str) -> wire.DecodedModel:
+        """A peer model for a merge or a scoring pass: ``get_decoded``, with
+        its delta base chain resolved now (it may fetch), so an unreachable
+        model or base raises here (KeyError/IOError)."""
+        with wall.span("exchange.fetch", round=self.rounds_done + 1,
+                       silo=self.silo_id):
+            dm = self.get_decoded(cid)
+            dm.resolve_base()
+        return dm
+
     def pull_and_merge(self):
         """Paper step 4-5: query orchestrator, pick models by policy, merge.
 
@@ -198,6 +210,11 @@ class SiloRuntime:
         With ``fed.reputation_weighted`` the per-model score collapse is
         weighted by on-chain reputation, so slashed scorers stop moving
         the aggregate."""
+        with wall.span("merge", round=self.rounds_done + 1,
+                       silo=self.silo_id):
+            return self._pull_and_merge()
+
+    def _pull_and_merge(self):
         src = self._read_contract()
         entries = src.get_latest_models_with_scores(
             exclude_owner=self.silo_id)
@@ -215,9 +232,7 @@ class SiloRuntime:
         peers = []
         for c in picked:  # may hit IPFS peers over the fabric
             try:
-                dm = self.get_decoded(c.cid)
-                dm.resolve_base()  # resolve the delta base chain (may fetch)
-                peers.append(dm)
+                peers.append(self.fetch(c.cid))
             except (KeyError, IOError):
                 self.env.emit(obsev.pull_fail(self.silo_id, c.cid))
         if not peers:
@@ -252,9 +267,11 @@ class SiloRuntime:
     def _encode(self):
         """Wire-encode this silo's params — ``repro.core.wire`` is the one
         codec path (raw | int8 | int8-delta | topk-delta envelopes)."""
-        return wire.encode_update(self.cluster.params, self.fed,
-                                  spec=self.flat_spec(),
-                                  base=self._delta_base()).to_store()
+        with wall.span("publish.encode", round=self.rounds_done + 1,
+                       silo=self.silo_id):
+            return wire.encode_update(self.cluster.params, self.fed,
+                                      spec=self.flat_spec(),
+                                      base=self._delta_base()).to_store()
 
     def train_and_submit(self, on_done: Callable):
         """Run a local FL round; put weights in the store; submit the CID."""
@@ -348,9 +365,7 @@ class SiloRuntime:
         decoded, kept = [], []
         for cid in cids:
             try:
-                dm = self.get_decoded(cid)
-                dm.resolve_base()  # fetch a delta's base chain now
-                decoded.append(dm)
+                decoded.append(self.fetch(cid))
                 kept.append(cid)
             except (KeyError, IOError):
                 # model unreachable (partition/churn): drop this assignment
@@ -426,10 +441,10 @@ class SiloRuntime:
 
     # -- checkpoint / restart -------------------------------------------------- #
     def checkpoint(self) -> str:
-        state = {"params": self.cluster.params,
-                 "round": np.asarray(self.rounds_done)}
-        cid = self.store.put(state)
-        return cid
+        with wall.span("publish.checkpoint", round=self.rounds_done,
+                       silo=self.silo_id):
+            return self.store.put({"params": self.cluster.params,
+                                   "round": np.asarray(self.rounds_done)})
 
     def restore_from(self, cid: str):
         state = self.store.get(cid)
@@ -650,6 +665,13 @@ class BaseOrchestrator:
     def live(self) -> List[SiloRuntime]:
         return [s for s in self.silos if s.alive]
 
+    def _chain(self, ledger, sender: str, method: str, rnd: int, **args):
+        """Submit a tx at the engine's simulated time, in a ``chain.submit``
+        wall span."""
+        with wall.span("chain.submit", round=rnd, silo=sender):
+            return ledger.submit(sender, method, logical_time=self.env.now,
+                                 **args)
+
     def summary(self) -> Dict:
         return {s.silo_id: s.metrics for s in self.silos}
 
@@ -693,8 +715,7 @@ class SyncOrchestrator(BaseOrchestrator):
         submitted: Dict[int, set] = {}
         cids: Dict[int, set] = {}
         for r in range(1, rounds + 1):
-            self.ledger.submit("orchestrator", "start_training",
-                               logical_time=self.env.now)
+            self._chain(self.ledger, "orchestrator", "start_training", r)
             self._net_phase(r, "train")
             t_round = self.env.now
             submitted[r] = set()
@@ -731,8 +752,8 @@ class SyncOrchestrator(BaseOrchestrator):
                                    ts, t_close, round=r)
             # scoring phase
             self._net_phase(r, "score")
-            assignments = self.ledger.submit("orchestrator", "start_scoring",
-                                             logical_time=self.env.now) or {}
+            assignments = self._chain(self.ledger, "orchestrator",
+                                      "start_scoring", r) or {}
             if self.fed.scorer == "multikrum":
                 self._score_multikrum(r)
             else:
@@ -760,8 +781,7 @@ class SyncOrchestrator(BaseOrchestrator):
                 self._run_window(
                     (score_deadline + self.fed.scorer_deadline_s)
                     if score_deadline is not None else None, scores_complete)
-            self.ledger.submit("orchestrator", "end_scoring",
-                               logical_time=self.env.now)
+            self._chain(self.ledger, "orchestrator", "end_scoring", r)
             for s in self.live():
                 s.rounds_done = r
                 s.checkpoint()
@@ -784,9 +804,7 @@ class SyncOrchestrator(BaseOrchestrator):
         reachable, decoded = [], []
         for e in entries:
             try:
-                dm = silo0.get_decoded(e.cid)
-                dm.resolve_base()  # resolve the delta base chain (may fetch)
-                decoded.append(dm)
+                decoded.append(silo0.fetch(e.cid))
                 reachable.append(e)
             except (KeyError, IOError):
                 self.env.emit(obsev.multikrum_fetch_fail(e.cid))
@@ -802,8 +820,8 @@ class SyncOrchestrator(BaseOrchestrator):
                 led = silo.ledger if silo is not None and silo.ledger \
                     is not None else self.ledger
                 try:
-                    led.submit(sid, "submit_score", cid=e.cid,
-                               score=float(sc), logical_time=self.env.now)
+                    self._chain(led, sid, "submit_score", r, cid=e.cid,
+                                score=float(sc))
                 except PermissionError:
                     self.env.emit(obsev.tx_revert(sid, "submit_score"))
 
@@ -813,9 +831,8 @@ class SyncOrchestrator(BaseOrchestrator):
         # heartbeat block never reached the engine's replica — is resampled,
         # and its eventual late score is disregarded by the contract
         if self.env.now > t_round:
-            stale = self.ledger.submit("orchestrator", "reassign_stale",
-                                       deadline_s=self.env.now - t_round,
-                                       logical_time=self.env.now) or []
+            stale = self._chain(self.ledger, "orchestrator", "reassign_stale",
+                                r, deadline_s=self.env.now - t_round) or []
             for d in stale:
                 rs = self._by_id(d["new"]) if d["new"] else None
                 if rs and rs.alive:
@@ -828,9 +845,9 @@ class SyncOrchestrator(BaseOrchestrator):
                     continue
                 silo = self._by_id(sid)
                 if silo is None or not silo.alive:
-                    repl = self.ledger.submit("orchestrator", "reassign_scorer",
-                                              cid=e.cid, dead=sid,
-                                              logical_time=self.env.now)
+                    repl = self._chain(self.ledger, "orchestrator",
+                                       "reassign_scorer", r, cid=e.cid,
+                                       dead=sid)
                     rs = self._by_id(repl) if repl else None
                     if rs and rs.alive:
                         rs.score_async(e.cid, e.owner)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.obs import wall
 
 
 def multikrum_scores_for_round(models: Sequence, m: int) -> List[float]:
@@ -31,7 +32,7 @@ def multikrum_scores_for_round(models: Sequence, m: int) -> List[float]:
     x, _ = ops.flatten_batch(models)
     scores = ops.multikrum_scores(x, m)
     # negate: lower distance sum = better; ONE device->host transfer
-    return (-np.asarray(scores)).tolist()
+    return (-wall.to_host(scores)).tolist()
 
 
 def multikrum_scores_for_decoded(decoded: Sequence, m: int) -> List[float]:
@@ -44,16 +45,18 @@ def multikrum_scores_for_decoded(decoded: Sequence, m: int) -> List[float]:
     rounds stack through the engine's batched-dequant ingest (one kernel
     pass per q8 length group, no per-model dequant loop). Either way the
     [M] score vector crosses to the host exactly once."""
-    if (all(d.is_q8 for d in decoded)
-            and len({int(d.q.shape[0]) for d in decoded}) == 1):
-        q = np.stack([np.asarray(d.q) for d in decoded])
-        s = np.stack([np.asarray(d.scales) for d in decoded])
-        scores = ops.multikrum_scores_q8(q, s, m)
-    else:
-        from repro.fed.scorebatch import stack_decoded_vecs
-        x = stack_decoded_vecs(decoded, int(decoded[0].n))
-        scores = ops.multikrum_scores(x, m)
-    return (-np.asarray(scores)).tolist()
+    with wall.span("score.multikrum"):
+        if (all(d.is_q8 for d in decoded)
+                and len({int(d.q.shape[0]) for d in decoded}) == 1):
+            q = np.stack([np.asarray(d.q) for d in decoded])
+            s = np.stack([np.asarray(d.scales) for d in decoded])
+            wall.count("h2d_bytes", q.nbytes + s.nbytes)
+            scores = ops.multikrum_scores_q8(q, s, m)
+        else:
+            from repro.fed.scorebatch import stack_decoded_vecs
+            x = stack_decoded_vecs(decoded, int(decoded[0].n))
+            scores = ops.multikrum_scores(x, m)
+        return (-wall.to_host(scores)).tolist()
 
 
 # JL projections are a pure function of (n, sketch_dim, seed) — regenerating

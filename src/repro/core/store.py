@@ -30,6 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.obs import wall
 from repro.obs.metrics import StatsView
 
 CHUNK_BYTES = 1 << 20  # 1 MiB blocks, IPFS-style
@@ -42,7 +43,7 @@ DECODED_CACHE_MAX = 64  # CIDs kept in each node's decoded-model cache
 
 def serialize_pytree(tree) -> bytes:
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    arrs = [np.asarray(l) for l in leaves]
+    arrs = [wall.to_host(l) for l in leaves]
     header = {
         "treedef": str(treedef),
         "leaves": [{"dtype": str(a.dtype), "shape": list(a.shape)} for a in arrs],
@@ -143,8 +144,16 @@ class StoreNode:
 
     # -- API ---------------------------------------------------------------- #
     def put(self, obj, *, pin: bool = True) -> str:
-        data = serialize_pytree(obj) if not isinstance(obj, bytes) else obj
-        cid = compute_cid(data)
+        with wall.span("publish.put", silo=self.node_id):
+            return self._put(obj, pin)
+
+    def _put(self, obj, pin: bool) -> str:
+        data = obj
+        if not isinstance(obj, bytes):
+            with wall.span("publish.serialize", silo=self.node_id):
+                data = serialize_pytree(obj)
+        with wall.span("publish.hash", silo=self.node_id):
+            cid = compute_cid(data)
         chunks = _chunk(data)
         with self._lock:
             self._blocks[cid] = chunks

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.obs import wall
 
 WIRE_VERSION = 1
 METHODS = ("raw", "int8", "int8-delta", "topk-delta")
@@ -124,7 +125,7 @@ class ModelEnvelope:
         for f in _ARRAY_FIELDS:
             a = getattr(self, f)
             if a is not None:
-                out[f] = np.asarray(a)
+                out[f] = wall.to_host(a)
         return out
 
     # -- reconstruction ----------------------------------------------------- #
@@ -195,16 +196,16 @@ def encode_vec(vec, method: str, *, base_vec=None, base_cid: str = "",
                              idx=idx, vals=delta[idx])
     # int8-delta: dense quantize, then tile-sparse elision
     q, s, _ = ops.quantize(delta)
-    qt = np.asarray(q).reshape(-1, QT)
-    s_np = np.asarray(s)
+    qt = wall.to_host(q).reshape(-1, QT)
+    s_np = wall.to_host(s)
     keep = np.abs(qt).max(axis=1) > 0        # drops padding + exact zeros
     if delta_rtol > 0:
         dpad = np.zeros((qt.shape[0] * QT,), np.float32)
-        dpad[:n] = np.asarray(delta)
+        dpad[:n] = wall.to_host(delta)
         damax = np.abs(dpad).reshape(-1, QT).max(axis=1)
         bpad = np.zeros_like(dpad)
-        bpad[:n] = np.asarray(base_vec, np.float32)[:n] if base_vec is not None \
-            else 0.0
+        bpad[:n] = wall.to_host(base_vec, np.float32)[:n] \
+            if base_vec is not None else 0.0
         bamax = np.abs(bpad).reshape(-1, QT).max(axis=1)
         # noise floor: one quantization step of the base tile — deltas that
         # never exceed delta_rtol steps are invisible at q8 wire fidelity

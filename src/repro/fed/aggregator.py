@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core.wire import DecodedModel
 from repro.kernels import ops
+from repro.obs import wall
 from repro.optim.fedopt import ServerOptimizer, make_server_optimizer
 
 
@@ -69,6 +70,7 @@ class SiloAggregator:
             # payloads arrive as host arrays: stack there, move once
             q = np.stack([np.asarray(p.q) for _, p in grp])
             s = np.stack([np.asarray(p.scales) for _, p in grp])
+            wall.count("h2d_bytes", q.nbytes + s.nbytes)
             gw = jnp.asarray(np.asarray([wi for wi, _ in grp], np.float32))
             mixed = mixed + ops.weighted_sum_q8(q, s, gw, n)
         for wi, p in f32_peers:

@@ -4,7 +4,6 @@ receive a global model and return locally-trained weights + sample count.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -12,13 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.api import Model
+from repro.obs import wall
 from repro.optim import make_optimizer
-
-
-@functools.lru_cache(maxsize=64)
-def _train_step_cache(model_key, opt_name, momentum):
-    return None  # placeholder; real cache below keyed by object id
-
 
 _STEP_CACHE: Dict[Tuple[int, str, float], callable] = {}
 
@@ -98,14 +92,30 @@ class Client:
                            "targets": jnp.asarray(tgts, jnp.int32)}
 
     def local_train(self, params, epochs: int = 2):
-        """Returns (trained params, n_samples, mean loss)."""
+        """Returns (trained params, n_samples, mean loss).
+
+        Each step is three wall spans: ``train.feed`` (the next batch: the
+        host gather and its copy to the device), ``train.dispatch`` (the
+        jitted step) and ``train.wait`` (the host blocked on the loss)."""
         step, opt = make_train_step(self.model, self.optimizer)
         opt_state = opt.init(params)
+        w = wall.active()
+        batches = self._batches(epochs)
         losses = []
-        for batch in self._batches(epochs):
-            params, opt_state, metrics = step(params, opt_state, batch,
-                                              jnp.float32(self.lr))
-            losses.append(float(metrics["loss"]))
+        while True:
+            with w.span("train.feed"):
+                batch = next(batches, None)
+            if batch is None:
+                break
+            with w.span("train.dispatch"):
+                lr = jnp.float32(self.lr)
+                params, opt_state, metrics = step(params, opt_state, batch, lr)
+            with w.span("train.wait"):
+                losses.append(float(metrics["loss"]))
+            if w.enabled:
+                w.count("host_syncs")
+                w.count("h2d_bytes", lr.nbytes + sum(
+                    a.nbytes for a in batch.values()))
         if self.byzantine == "signflip":
             params = jax.tree.map(lambda p: -p, params)
         elif self.byzantine == "noise":

@@ -7,7 +7,6 @@ byzantine (submitting poisoned models — paper Figure 7).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import jax
@@ -18,6 +17,7 @@ from repro.fed import scorebatch
 from repro.fed.aggregator import SiloAggregator
 from repro.fed.client import Client, validate_byzantine
 from repro.models.api import Model
+from repro.obs import wall
 
 
 class Cluster:
@@ -48,22 +48,21 @@ class Cluster:
         sampled edge clients train on their device profiles and FedAvg up
         here — the multilevel pre-round the paper compares against, charged
         on the fabric when one is wired."""
-        t0 = time.perf_counter()
         if self.edge_fleet is not None:
             self.params, m = self.edge_fleet.train_round(self.params)
             self._perturb()
             self.round += 1
             m["round"] = self.round
-            m["wall_s"] = time.perf_counter() - t0
             return m
         results = [c.local_train(self.params, self.local_epochs)
                    for c in self.clients]
-        self.params = self.aggregator.aggregate_clients(results)
+        with wall.span("train.fedavg", round=self.round + 1,
+                       silo=self.silo_id):
+            self.params = self.aggregator.aggregate_clients(results)
         self._perturb()
         self.round += 1
-        wall = time.perf_counter() - t0
         mean_loss = float(np.mean([r[2] for r in results]))
-        return {"round": self.round, "client_loss": mean_loss, "wall_s": wall}
+        return {"round": self.round, "client_loss": mean_loss}
 
     def _perturb(self) -> None:
         """Silo-level byzantine poisoning of the aggregated model."""

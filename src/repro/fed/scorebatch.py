@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.obs import wall
 
 BATCH_SIZE = 256          # eval batch width (matches the pre-engine loop)
 MAX_PREPARED = 8          # device-resident test-set layouts kept process-wide
@@ -78,6 +79,7 @@ def stack_decoded_vecs(decoded: Sequence, n: int):
     for idxs in groups.values():
         q = np.stack([np.asarray(decoded[i].q) for i in idxs])
         s = np.stack([np.asarray(decoded[i].scales) for i in idxs])
+        wall.count("h2d_bytes", q.nbytes + s.nbytes)
         mat = ops.dequantize_batch(q, s, n)
         if len(idxs) == K:  # uniform int8 round (the default compression's
             return mat      # hot path): the batch IS the answer, no restack
@@ -127,6 +129,7 @@ def stack_decoded(decoded: Sequence, spec):
             and len({int(d.q.shape[0]) for d in decoded}) == 1):
         q = np.stack([np.asarray(d.q) for d in decoded])
         s = np.stack([np.asarray(d.scales) for d in decoded])
+        wall.count("h2d_bytes", q.nbytes + s.nbytes)
         return _dequantize_stacked(q, s, spec)
     n = ops.spec_length(spec)
     dtype = _leaf_dtype(spec)
@@ -265,15 +268,17 @@ class BatchedScorer:
     def evaluate_stacked(self, stacked) -> np.ndarray:
         """stacked: pytree with leaves [K, ...] -> host [2, K] (loss, acc)
         via exactly ONE device->host transfer."""
-        p = self._prep()
-        self.calls += 1
-        K = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
-        if p["kind"] == "empty":     # degenerate LM stream: matches the
-            return np.stack([np.zeros(K), np.ones(K)])  # pre-engine fallback
-        out = _eval_fn(self.cluster.model, p["kind"])(stacked, *p["args"])
-        host = np.asarray(out)       # the single device->host transfer
-        self.host_syncs += 1
-        return host
+        with wall.span("score.eval"):
+            p = self._prep()
+            self.calls += 1
+            K = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
+            if p["kind"] == "empty":
+                # degenerate LM stream: matches the pre-engine fallback
+                return np.stack([np.zeros(K), np.ones(K)])
+            out = _eval_fn(self.cluster.model, p["kind"])(stacked, *p["args"])
+            host = wall.to_host(out)     # the single device->host transfer
+            self.host_syncs += 1
+            return host
 
 
 def get_scorer(cluster) -> BatchedScorer:

@@ -74,6 +74,7 @@ def wsum_q8(q, scales, w, n: int, *, interpret: bool = False):
                   pl.BlockSpec((M, BLOCK_ROWS, 128), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((BLOCK_ROWS, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n // 128, 128), jnp.float32),
+        name="wsum_q8",
         interpret=interpret,
     )(ws[:, :, None], q.reshape(M, N // 128, 128))
     return out.reshape(n)
@@ -102,6 +103,7 @@ def add_q8_delta(base, q, scales, *, interpret: bool = False):
         in_specs=[blk, blk, pl.BlockSpec((QPB, 1), lambda i: (i, 0))],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((n // 128, 128), jnp.float32),
+        name="add_q8_delta",
         interpret=interpret,
     )(base.astype(jnp.float32).reshape(n // 128, 128),
       q.reshape(N // 128, 128), scales[:, None])
@@ -156,6 +158,7 @@ def gram_q8(q, scales, *, interpret: bool = False):
                   pl.BlockSpec((M, BLOCK_ROWS, 128), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((M, M), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((M, M), jnp.float32),
+        name="gram_q8",
         interpret=interpret,
     )(scales.astype(jnp.float32)[:, :, None], q.reshape(M, rows, 128))
     return g, jnp.diagonal(g)[:, None]

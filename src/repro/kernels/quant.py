@@ -75,6 +75,7 @@ def quantize(x, *, interpret: bool = False):
                    pl.BlockSpec((LANE, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((Np // 128, 128), jnp.int8),
                    jax.ShapeDtypeStruct((Np // TILE, 1), jnp.float32)],
+        name="quantize",
         interpret=interpret,
     )(x.reshape(n // 128, 128))
     return q.reshape(-1), s[:, 0]
@@ -98,6 +99,7 @@ def dequantize_batch(q, scales, *, dtype=jnp.float32, interpret: bool = False):
                   pl.BlockSpec((None, LANE, 1), lambda k, i: (k, i, 0))],
         out_specs=pl.BlockSpec((None, BLOCK_ROWS, 128), lambda k, i: (k, i, 0)),
         out_shape=jax.ShapeDtypeStruct((K, rows, 128), dtype),
+        name="dequantize_batch",
         interpret=interpret,
     )(q.reshape(K, rows, 128), scales[:, :, None])
     return x.reshape(K, N)
@@ -117,6 +119,7 @@ def dequantize(q, scales, n: int, *, dtype=jnp.float32,
                   pl.BlockSpec((LANE, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_ROWS, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n // 128, 128), dtype),
+        name="dequantize",
         interpret=interpret,
     )(q.reshape(Np // 128, 128), scales[:, None])
     return x.reshape(-1)

@@ -1,6 +1,6 @@
 """repro.obs — observability over the simulated runtime.
 
-One subsystem, four pieces (see the module docstrings for detail):
+One subsystem, five pieces (see the module docstrings for detail):
 
   * ``events``  — typed TraceEvents, string-compatible with the legacy
     ``env.trace`` f-strings;
@@ -9,17 +9,25 @@ One subsystem, four pieces (see the module docstrings for detail):
   * ``metrics`` — declared per-component stat schemas (``StatsView``) and
     the run-wide ``MetricsRegistry`` that indexes them;
   * ``export``  — Chrome-trace-event JSON (Perfetto-loadable) + flat
-    metrics snapshots; ``report`` is the CLI over the export.
+    metrics snapshots; ``report`` is the CLI over the export;
+  * ``wall``    — the program's own wall-clock spans and counters (host
+    seconds, syncs, H2D bytes), each span also a ``jax.profiler``
+    annotation on the device trace's clock (``NULL_WALL`` when off).
+
+The tracer's spans are the simulator's timeline, not a measurement; the
+wall's are measured.
 
 ``Observability`` is the per-run bundle the orchestrator owns: it turns an
-``ObsConfig`` into a tracer (real or null) plus a registry, adopts every
-component's stats view, and exports the trace at run end.
+``ObsConfig`` into a tracer (real or null) plus a registry, switches the
+process's wall to ``ObsConfig.wall``, adopts every component's stats view
+(the wall's too, when on), and exports the trace at run end.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 from repro.config import ObsConfig
+from repro.obs import wall
 from repro.obs.metrics import (SCHEMAS, Histogram, MetricsRegistry,
                                StatsView, declared_keys)
 from repro.obs.tracer import NULL_TRACER, NullTracer, Span, Tracer
@@ -29,7 +37,7 @@ from repro.obs.export import (chrome_trace, validate_chrome_trace,
 __all__ = ["ObsConfig", "Observability", "SCHEMAS", "Histogram",
            "MetricsRegistry", "StatsView", "declared_keys", "NULL_TRACER",
            "NullTracer", "Span", "Tracer", "chrome_trace",
-           "validate_chrome_trace", "write_chrome_trace"]
+           "validate_chrome_trace", "write_chrome_trace", "wall"]
 
 
 class Observability:
@@ -40,6 +48,9 @@ class Observability:
         self.registry = MetricsRegistry()
         self.tracer = Tracer(registry=self.registry) if self.cfg.enabled \
             else NULL_TRACER
+        self.wall = wall.switch(self.cfg.wall)
+        if self.wall.enabled:
+            self.registry.adopt(self.wall.stats)
 
     @property
     def enabled(self) -> bool:

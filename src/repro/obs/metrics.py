@@ -6,7 +6,8 @@ Now each component's key set is *declared* once, with zero defaults and a
 metric kind per key:
 
   * ``counter``   — monotonically increasing int,
-  * ``seconds``   — monotonically accumulating float (simulated seconds),
+  * ``seconds``   — monotonically accumulating float (simulated seconds;
+    host seconds on ``time.perf_counter`` in the ``wall`` component),
   * ``gauge``     — point-in-time value (e.g. ``max_reorg_depth``).
 
 ``StatsView`` is a schema-enforcing MutableMapping that **is** the backing
@@ -105,6 +106,21 @@ SCHEMAS: Dict[str, Dict[str, str]] = {
         "wal_blocks": "counter", "wal_replayed": "counter",
         "wal_replay_bytes": "counter",
     },
+}
+
+# repro.obs.wall (one per process): the program's wall-clock spans, each as
+# its self seconds (``<span>_s``) and calls (``<span>_n``), and its counters
+WALL_SPANS = (
+    "train.feed", "train.dispatch", "train.wait", "train.fedavg",
+    "exchange.fetch", "publish.encode", "publish.put", "publish.serialize",
+    "publish.hash", "publish.checkpoint", "score.eval", "score.multikrum",
+    "merge", "chain.submit",
+)
+SCHEMAS["wall"] = {
+    **{f"{s}_{k}": kind for s in WALL_SPANS
+       for k, kind in (("s", "seconds"), ("n", "counter"))},
+    "host_syncs": "counter",     # device values the host blocked on
+    "h2d_bytes": "counter",      # bytes put on the device
 }
 
 COUNTER_KINDS = ("counter", "seconds")

@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never while a module is
 imported: only the worker that runs these tests loads the TPU compiler.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -79,6 +80,46 @@ def test_kernel_lowers_for_v5e_at_lm_width(one_chip, name):
         # one of N f32, the padding another)
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < N_PADDED, (name, temp)
+
+
+# the paper CNN's 62,006 parameters, to a multiple of 128
+SMALL = 62_080
+SMALL_PADDED = quant.padded(SMALL)
+
+# each kernel called without its own jit, from a lambda: the device op took
+# the enclosing jitted function's name (``_lambda_``) until the pallas_call
+# named it
+NAMED = {
+    "wsum_q8": (lambda q, s, w: q8agg.wsum_q8.__wrapped__(q, s, w, SMALL),
+                [((2, SMALL_PADDED), jnp.int8),
+                 ((2, SMALL_PADDED // QT), jnp.float32), ((2,), jnp.float32)]),
+    "add_q8_delta": (lambda b, q, s: q8agg.add_q8_delta.__wrapped__(b, q, s),
+                     [((SMALL,), jnp.float32), ((SMALL_PADDED,), jnp.int8),
+                      ((SMALL_PADDED // QT,), jnp.float32)]),
+    "gram_q8": (lambda q, s: q8agg.gram_q8.__wrapped__(q, s),
+                [((3, SMALL_PADDED), jnp.int8),
+                 ((3, SMALL_PADDED // QT), jnp.float32)]),
+    "quantize": (lambda x: quant.quantize.__wrapped__(x),
+                 [((SMALL,), jnp.float32)]),
+    "dequantize": (lambda q, s: quant.dequantize.__wrapped__(q, s, SMALL),
+                   [((SMALL_PADDED,), jnp.int8),
+                    ((SMALL_PADDED // QT,), jnp.float32)]),
+    "dequantize_batch": (lambda q, s: quant.dequantize_batch.__wrapped__(q, s),
+                         [((2, SMALL_PADDED), jnp.int8),
+                          ((2, SMALL_PADDED // QT), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_a_kernels_device_op_takes_the_kernels_name(one_chip, name):
+    """A profiler trace names a kernel's op after the kernel, whatever jitted
+    function calls it (``wsum_q8_roofline`` finds ``wsum_q8.N`` so)."""
+    fn, shapes = NAMED[name]
+    text = _compile(fn, *[_shape(one_chip, s, d) for s, d in shapes]
+                    ).as_text()
+    ops = re.findall(r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"',
+                     text)
+    assert len(ops) == 1 and re.fullmatch(rf"{name}(\.\d+)?", ops[0]), ops
 
 
 def test_lm_train_step_compiles_and_fits_one_v5e(one_chip):
