@@ -94,9 +94,11 @@ class Client:
     def local_train(self, params, epochs: int = 2):
         """Returns (trained params, n_samples, mean loss).
 
-        Each step is three wall spans: ``train.feed`` (the next batch: the
-        host gather and its copy to the device), ``train.dispatch`` (the
-        jitted step) and ``train.wait`` (the host blocked on the loss)."""
+        Each step is two wall spans: ``train.feed`` (the next batch: the
+        host gather and its copy to the device) and ``train.dispatch`` (the
+        jitted step). The losses stay on the device until the last step;
+        ``train.wait`` is their one read, which starts every copy before it
+        blocks, so the host runs ahead of the device meanwhile."""
         step, opt = make_train_step(self.model, self.optimizer)
         opt_state = opt.init(params)
         w = wall.active()
@@ -110,16 +112,18 @@ class Client:
             with w.span("train.dispatch"):
                 lr = jnp.float32(self.lr)
                 params, opt_state, metrics = step(params, opt_state, batch, lr)
-            with w.span("train.wait"):
-                losses.append(float(metrics["loss"]))
+            losses.append(metrics["loss"])
             if w.enabled:
-                w.count("host_syncs")
                 w.count("h2d_bytes", lr.nbytes + sum(
                     a.nbytes for a in batch.values()))
+        with w.span("train.wait"):
+            # float64, as the Python floats of a per-step read were
+            losses = np.asarray(jax.device_get(losses), np.float64)
+        w.count("host_syncs", len(losses))
         if self.byzantine == "signflip":
             params = jax.tree.map(lambda p: -p, params)
         elif self.byzantine == "noise":
             params = jax.tree.map(
                 lambda p: p + jnp.asarray(
                     self.rng.normal(0, 1.0, p.shape), p.dtype), params)
-        return params, self.n_samples, float(np.mean(losses)) if losses else 0.0
+        return params, self.n_samples, float(np.mean(losses)) if losses.size else 0.0

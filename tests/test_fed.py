@@ -5,10 +5,12 @@ import numpy as np
 import pytest
 
 from repro.config import FedConfig
-from repro.configs import get_config
+from repro.configs import get_config, get_smoke_config
+from repro.fed import client as client_mod
 from repro.fed.aggregator import SiloAggregator, fedavg_params
-from repro.fed.client import Client
+from repro.fed.client import Client, make_train_step
 from repro.models import build_model
+from repro.obs import wall
 from repro.optim.fedopt import make_server_optimizer
 from repro.optim.local import make_optimizer
 from repro.optim.schedules import make_schedule
@@ -115,3 +117,76 @@ def test_byzantine_client_flips_sign():
     v0 = np.concatenate([np.asarray(l).ravel() for l in jax.tree.leaves(params)])
     v1 = np.concatenate([np.asarray(l).ravel() for l in jax.tree.leaves(new_params)])
     assert np.dot(v0, v1) < 0
+
+
+def _shard(kind):
+    """A model, a client shard and initial params: an image shard of the
+    paper CNN, or a token stream of a smoke-sized qwen3."""
+    rng = np.random.default_rng(1)
+    if kind == "image":
+        model = build_model(get_config("paper-cnn"))
+        data = {"x": rng.normal(0, 1, (48, 32, 32, 3)).astype(np.float32),
+                "y": rng.integers(0, 10, 48).astype(np.int32)}
+    else:
+        cfg = get_smoke_config("qwen3-1.7b")
+        model = build_model(cfg)
+        data = {"tokens": rng.integers(0, cfg.vocab_size, 400).astype(np.int32),
+                "seq_len": 16, "steps_per_epoch": 2}
+    return model, data, model.init(jax.random.PRNGKey(0))
+
+
+def _client(model, data):
+    return Client("c0", model, data, batch_size=8, lr=0.05, seed=7)
+
+
+@pytest.mark.parametrize("kind", ["image", "tokens"])
+def test_local_train_matches_a_loop_that_reads_each_loss(kind):
+    """Reading the losses once after the last step gives the params and the
+    mean loss of a loop that reads every loss on its step, bit for bit."""
+    model, data, params = _shard(kind)
+    got, _, loss = _client(model, data).local_train(params, epochs=2)
+    ref = _client(model, data)
+    step, opt = make_train_step(model, ref.optimizer)
+    want, opt_state, losses = params, opt.init(params), []
+    for batch in ref._batches(2):
+        want, opt_state, metrics = step(want, opt_state, batch,
+                                        jnp.float32(ref.lr))
+        losses.append(float(metrics["loss"]))
+    assert len(losses) == (12 if kind == "image" else 4)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert loss == float(np.mean(losses))
+
+
+def test_local_train_steps_through_the_modules_train_step(monkeypatch):
+    """The seam a caller may wrap: ``make_train_step`` is looked up on the
+    module at call time and its step called once per batch, in order, with
+    the batch dict; with the wall on, one ``train.wait`` per call and one
+    host sync per loss read back."""
+    model, data, params = _shard("image")
+    want = list(_client(model, data)._batches(2))
+    make_step, fed = client_mod.make_train_step, []
+
+    def counting(model, opt_name="sgd", momentum=0.0):
+        step, opt = make_step(model, opt_name, momentum)
+
+        def counted(params, opt_state, batch, lr):
+            fed.append(batch)
+            return step(params, opt_state, batch, lr)
+        return counted, opt
+
+    monkeypatch.setattr(client_mod, "make_train_step", counting)
+    wall.switch(True)
+    try:
+        _client(model, data).local_train(params, epochs=2)
+        t = wall.totals()
+    finally:
+        wall.switch(False)
+    assert len(fed) == len(want) == 12
+    for got, exp in zip(fed, want):
+        assert isinstance(got, dict) and list(got) == list(exp)
+        for k in exp:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(exp[k]))
+    assert t["train.wait_n"] == 1 and t["train.dispatch_n"] == len(want)
+    assert t["host_syncs"] == len(want)
