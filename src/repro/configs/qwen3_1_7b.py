@@ -1,7 +1,8 @@
 """Qwen3-1.7B: dense decoder LM with qk-norm and GQA.
 
-[hf:Qwen/Qwen3-8B (family config); hf] 28L d_model=2048 16H (GQA kv=8)
-d_ff=6144 vocab=151936, head_dim=128, RMSNorm on q/k per head.
+Source: https://huggingface.co/Qwen/Qwen3-1.7B/blob/main/config.json.
+28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936 (tied), head_dim=128,
+RMSNorm on q/k per head (eps 1e-6), RoPE theta 1e6, bf16.
 """
 from repro.config import ModelConfig, replace
 
@@ -20,7 +21,7 @@ CONFIG = ModelConfig(
     gated_mlp=True,
     rope_theta=1e6,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="https://huggingface.co/Qwen/Qwen3-1.7B/blob/main/config.json",
 )
 
 

@@ -88,6 +88,7 @@ class SiloRuntime:
         # envelope references (receivers resolve it from their own stores)
         self.last_global_cid: Optional[str] = None
         self.last_self_score = float("-inf")
+        self.last_checkpoint: Optional[str] = None  # its latest checkpoint
         self.metrics: List[Dict] = []
         # injected scorer fault (adversarial scenarios): None, or a
         # ("collude", clique) / ("byzantine", _) pair set by the fault layer
@@ -443,8 +444,28 @@ class SiloRuntime:
     def checkpoint(self) -> str:
         with wall.span("publish.checkpoint", round=self.rounds_done,
                        silo=self.silo_id):
-            return self.store.put({"params": self.cluster.params,
-                                   "round": np.asarray(self.rounds_done)})
+            self.last_checkpoint = self.store.put(
+                {"params": self.cluster.params,
+                 "round": np.asarray(self.rounds_done)})
+        return self.last_checkpoint
+
+    def retain(self) -> List[str]:
+        """End of a round: the store node keeps what the protocol can still
+        read (``live_cids`` of the contract views this silo reads, its own
+        latest announce and checkpoint, copies of models its replica of the
+        chain has not seen land yet, and their delta bases) and drops the
+        rest from memory. Returns the CIDs dropped."""
+        keep = live_cids(self.contract)
+        if self.fed.finality_depth > 0:
+            keep |= live_cids(self._read_contract())
+        keep.update(c for c in (self.last_global_cid, self.last_checkpoint)
+                    if c)
+        # a gossiped or prefetched model runs ahead of its submission's
+        # block: dropping it would only make the scorer fetch it again
+        keep.update(c for c in self.store.replicas()
+                    if c not in self.contract.models)
+        delta = wire.resolve_method(self.fed.compression).endswith("-delta")
+        return self.store.retain(keep, with_bases=delta)
 
     def restore_from(self, cid: str):
         state = self.store.get(cid)
@@ -452,6 +473,25 @@ class SiloRuntime:
                                             {k: v for k, v in state.items()
                                              if k.startswith("['params']")})
         return state
+
+
+def live_cids(contract: UnifyFLContract) -> set:
+    """The model CIDs ``contract`` can still hand out: each owner's latest,
+    deferred stragglers, and in Sync the models of the open and the previous
+    round (scoring, reassignment and merges read them), in Async the models
+    whose assigned scores are not all in."""
+    keep = set(contract.latest_by_owner.values())
+    keep.update(d["cid"] for d in contract.deferred)
+    if contract.mode == "sync":
+        # models enter in round order, so the newest rounds come last
+        for e in reversed(contract.models.values()):
+            if e.round < contract.round - 1:
+                break
+            keep.add(e.cid)
+    else:
+        keep.update(e.cid for e in contract.models.values()
+                    if not set(e.assigned) <= set(e.scores))
+    return keep
 
 
 def _rebuild_like(like, flat: Dict[str, np.ndarray]):
@@ -785,6 +825,9 @@ class SyncOrchestrator(BaseOrchestrator):
             for s in self.live():
                 s.rounds_done = r
                 s.checkpoint()
+                # at once, so that one silo's old checkpoint goes before the
+                # next silo's new one arrives
+                s.retain()
             self._mark_round(r)
             if tr.enabled:
                 tr.span_at("phase.round", "orchestrator/rounds",
@@ -885,6 +928,10 @@ class AsyncOrchestrator(BaseOrchestrator):
                 # ... and the first silo *finishing* round r fires "score"
                 self._net_phase(s.rounds_done, "score")
                 s.checkpoint()
+                # a silo whose own rounds are over still scores its peers,
+                # so every silo's round end bounds every live store
+                for x in self.live():
+                    x.retain()
                 self._mark_round(s.rounds_done, s.silo_id)
                 self.env.schedule(0.0, lambda: loop(s), f"{s.silo_id}:loop")
 

@@ -1,10 +1,12 @@
 """Content-addressed store — the IPFS analogue (paper §2.4, §3.4.2).
 
 Properties kept from IPFS: content addressing (CID = SHA-256 of canonical
-bytes), integrity verification on fetch, immutability, per-node local blocks
-with peer fetch-and-cache (DHT-like), pinning, and hosting store nodes on the
-aggregator machines themselves. Serialization is a deterministic pytree codec
-(JSON header + raw array bytes), optionally chunked like IPFS blocks.
+bytes, by 1 MiB blocks past one block), integrity verification on fetch,
+immutability, per-node local blocks with peer fetch-and-cache (DHT-like),
+pinning, and hosting store nodes on the aggregator machines themselves.
+Serialization is a deterministic pytree codec (JSON header + raw array
+bytes). A node holds each CID's bytes as one buffer, never written once it
+lands, which the decoded models it hands out view without a copy.
 
 A ``StoreNetwork`` connects per-silo ``StoreNode``s; ``get`` falls back to
 peers and caches locally (exactly the IPFS behaviour the paper relies on for
@@ -17,6 +19,13 @@ the (src, dst) link, and per-node accounting lands in ``stats``
 (``bytes_in`` / ``bytes_out`` / ``fetch_time`` / ``replica_hits`` /
 ``prefetch_hits``). ``drain_transfer_time`` hands the accumulated charge to
 the orchestrator so WAN time enters the simulated clock.
+
+Memory is bounded by ``retain``: the caller names the CIDs the protocol can
+still read, and the node drops the rest from memory (blocks, pins, decoded
+models and, where nothing on disk serves them, its provider records). The
+wall counters ``store_bytes_in`` and ``store_bytes_dropped`` count every
+buffer a node takes in and lets go, so their difference is what all nodes
+hold.
 """
 from __future__ import annotations
 
@@ -25,6 +34,7 @@ import json
 import os
 import threading
 from collections import OrderedDict
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -33,15 +43,18 @@ import numpy as np
 from repro.obs import wall
 from repro.obs.metrics import StatsView
 
-CHUNK_BYTES = 1 << 20  # 1 MiB blocks, IPFS-style
 DECODED_CACHE_MAX = 64  # CIDs kept in each node's decoded-model cache
+CID_BLOCK = 1 << 20     # bytes a CID hashes per block (IPFS's chunk size)
+HASH_THREADS = min(8, os.cpu_count() or 1)  # hashlib releases the GIL
 
 
 # --------------------------------------------------------------------------- #
 # Deterministic pytree codec
 # --------------------------------------------------------------------------- #
 
-def serialize_pytree(tree) -> bytes:
+def serialize_pytree(tree) -> bytearray:
+    """JSON header, then every leaf's raw bytes, written once into one
+    buffer (a payload is never copied twice on its way into the store)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     arrs = [wall.to_host(l) for l in leaves]
     header = {
@@ -51,44 +64,56 @@ def serialize_pytree(tree) -> bytes:
                   jax.tree_util.tree_flatten_with_path(tree)[0]],
     }
     hb = json.dumps(header, sort_keys=True).encode()
-    out = [len(hb).to_bytes(8, "little"), hb]
+    out = bytearray(8 + len(hb) + sum(a.nbytes for a in arrs))
+    out[:8] = len(hb).to_bytes(8, "little")
+    off = 8 + len(hb)
+    out[8:off] = hb
+    view = memoryview(out)
     for a in arrs:
-        out.append(np.ascontiguousarray(a).tobytes())
-    return b"".join(out)
+        view[off:off + a.nbytes] = np.ascontiguousarray(a).reshape(-1).view(
+            np.uint8)
+        off += a.nbytes
+    return out
 
 
 def _path_str(path) -> str:
     return jax.tree_util.keystr(path)
 
 
-def deserialize_pytree(data: bytes, like=None):
+def deserialize_pytree(data, like=None):
     """If ``like`` (a pytree prototype) is given, reconstruct its structure;
-    otherwise return a flat dict path -> array."""
+    otherwise return a flat dict path -> array. The arrays are read-only
+    views of ``data``, not copies."""
     hlen = int.from_bytes(data[:8], "little")
-    header = json.loads(data[8:8 + hlen].decode())
+    header = json.loads(bytes(data[8:8 + hlen]).decode())
     off = 8 + hlen
     arrs = []
     for spec in header["leaves"]:
         dt = np.dtype(spec["dtype"])
         n = int(np.prod(spec["shape"])) if spec["shape"] else 1
-        nb = n * dt.itemsize
-        a = np.frombuffer(data[off:off + nb], dtype=dt).reshape(spec["shape"])
-        arrs.append(a)
-        off += nb
+        a = np.frombuffer(data, dtype=dt, count=n, offset=off)
+        a.flags.writeable = False
+        arrs.append(a.reshape(spec["shape"]))
+        off += n * dt.itemsize
     if like is not None:
         treedef = jax.tree_util.tree_structure(like)
         return jax.tree_util.tree_unflatten(treedef, arrs)
     return dict(zip(header["paths"], arrs))
 
 
-def compute_cid(data: bytes) -> str:
-    return "bafy" + hashlib.sha256(data).hexdigest()
-
-
-def _chunk(data: bytes) -> List[bytes]:
-    """Split payload bytes into IPFS-style blocks."""
-    return [data[i:i + CHUNK_BYTES]
-            for i in range(0, len(data), CHUNK_BYTES)] or [b""]
+def compute_cid(data) -> str:
+    """The CID of a payload: the SHA-256 of its bytes or, past one
+    ``CID_BLOCK``, the SHA-256 of its blocks' SHA-256 digests (a one-level
+    Merkle DAG, as IPFS chunks large content), the blocks hashed on a few
+    threads. The prefixes keep the two forms apart."""
+    if len(data) <= CID_BLOCK:
+        return "bafy" + hashlib.sha256(data).hexdigest()
+    view = memoryview(data).cast("B")
+    with ThreadPoolExecutor(HASH_THREADS) as pool:
+        digests = pool.map(
+            lambda i: hashlib.sha256(view[i:i + CID_BLOCK]).digest(),
+            range(0, len(view), CID_BLOCK))
+        return "bafb" + hashlib.sha256(b"".join(digests)).hexdigest()
 
 
 # --------------------------------------------------------------------------- #
@@ -102,7 +127,9 @@ class StoreNode:
         self.node_id = node_id
         self.root = root
         self.network: Optional["StoreNetwork"] = None
-        self._blocks: Dict[str, List[bytes]] = {}
+        # cid -> its bytes, one buffer shared with the decoded models that
+        # view it (never a second copy of a payload)
+        self._blocks: Dict[str, bytes] = {}
         self._pins: set = set()
         self._peers: List["StoreNode"] = []
         self._lock = threading.Lock()
@@ -149,14 +176,13 @@ class StoreNode:
 
     def _put(self, obj, pin: bool) -> str:
         data = obj
-        if not isinstance(obj, bytes):
+        if not isinstance(obj, (bytes, bytearray)):
             with wall.span("publish.serialize", silo=self.node_id):
                 data = serialize_pytree(obj)
         with wall.span("publish.hash", silo=self.node_id):
             cid = compute_cid(data)
-        chunks = _chunk(data)
         with self._lock:
-            self._blocks[cid] = chunks
+            self._hold(cid, data)
             if pin:
                 self._pins.add(cid)
             self.stats["puts"] += 1
@@ -173,11 +199,22 @@ class StoreNode:
         return cid in self._blocks or (
             self.root and os.path.exists(os.path.join(self.root, cid)))
 
+    def _hold(self, cid: str, data: bytes) -> None:
+        """Keep ``data`` as ``cid``'s block (lock held); new bytes count in."""
+        if cid not in self._blocks:
+            wall.count("store_bytes_in", len(data))
+        self._blocks[cid] = data
+
+    def held_bytes(self) -> int:
+        """Bytes of the blocks this node holds in memory."""
+        with self._lock:
+            return sum(len(d) for d in self._blocks.values())
+
     def read_local(self, cid: str) -> Optional[bytes]:
         """Local blocks / disk only — never touches the network."""
         with self._lock:
             if cid in self._blocks:
-                return b"".join(self._blocks[cid])
+                return self._blocks[cid]
         if self.root:
             p = os.path.join(self.root, cid)
             if os.path.exists(p):
@@ -202,7 +239,7 @@ class StoreNode:
                           f"{self.node_id}")
         with self._lock:
             if cid not in self._blocks:
-                self._blocks[cid] = _chunk(data)
+                self._hold(cid, data)
                 self.stats["bytes_in"] += len(data)
                 # a demand fetch that raced us in already paid for these
                 # bytes — only a genuinely landing prefetch earns the credit
@@ -236,7 +273,7 @@ class StoreNode:
                     raise IOError(f"integrity failure fetching {cid} "
                                   f"from {peer.node_id}")
                 with self._lock:
-                    self._blocks[cid] = _chunk(data)
+                    self._hold(cid, data)
                     self.stats["peer_fetches"] += 1
                     self.stats["bytes_fetched"] += len(data)
                 return data
@@ -278,7 +315,7 @@ class StoreNode:
             charged = fab.transfer(src_id, self.node_id, cid, len(data),
                                    kind=kind)
             with self._lock:
-                self._blocks[cid] = _chunk(data)
+                self._hold(cid, data)
                 self.stats["peer_fetches"] += 1
                 self.stats["bytes_fetched"] += len(data)
                 self.stats["bytes_in"] += len(data)
@@ -367,7 +404,58 @@ class StoreNode:
         with self._lock:
             for cid in list(self._blocks):
                 if cid not in self._pins:
-                    del self._blocks[cid]
+                    wall.count("store_bytes_dropped",
+                               len(self._blocks.pop(cid)))
+
+    def replicas(self) -> set:
+        """CIDs this node holds but did not put itself: fetched, gossiped or
+        prefetched copies."""
+        with self._lock:
+            return {*self._blocks, *self._decoded_cids} - self._pins
+
+    def base_of(self, cid: str) -> str:
+        """The delta base a held CID references ('' for none, or where this
+        node holds neither the CID's blocks nor its decoded model)."""
+        with self._lock:
+            key = self._decoded_cids.get(cid)
+            if key is not None:
+                return key[1]
+            data = self._blocks.get(cid)
+        if data is None:
+            return ""
+        from repro.core.wire import base_cid_of_store
+        return base_cid_of_store(deserialize_pytree(data))
+
+    def retain(self, keep, *, with_bases: bool = False) -> List[str]:
+        """Drop from memory every CID outside ``keep`` (with ``with_bases``,
+        also outside the delta base chains of ``keep``'s CIDs): its blocks,
+        its pin, its decoded model, and, where no file on disk still serves
+        it, this node's provider record in the fabric. Files under ``root``
+        stay. Returns the CIDs dropped."""
+        with wall.span("store.retain", silo=self.node_id):
+            live = set(keep)
+            if with_bases:
+                for cid in list(live):
+                    base = self.base_of(cid)
+                    while base and base not in live:
+                        live.add(base)
+                        base = self.base_of(base)
+            with self._lock:
+                dropped = [c for c in {*self._blocks, *self._decoded_cids}
+                           if c not in live]
+                for cid in dropped:
+                    data = self._blocks.pop(cid, None)
+                    if data is not None:
+                        wall.count("store_bytes_dropped", len(data))
+                    self._pins.discard(cid)
+                    self._decoded.pop(self._decoded_cids.pop(cid, None), None)
+                    self._prefetched.discard(cid)
+            fab = self.fabric
+            if fab is not None:
+                for cid in dropped:
+                    if not self.has(cid):
+                        fab.drop_provider(cid, self.node_id)
+        return dropped
 
 
 class StoreNetwork:
@@ -384,9 +472,8 @@ class StoreNetwork:
         self.fabric = fabric
         for node in self.nodes.values():
             fabric.register_node(node.node_id)
-            for cid, chunks in node._blocks.items():
-                fabric.publish(cid, node.node_id,
-                               sum(len(c) for c in chunks))
+            for cid, data in node._blocks.items():
+                fabric.publish(cid, node.node_id, len(data))
 
     def add_node(self, node_id: str, root: Optional[str] = None) -> StoreNode:
         node = StoreNode(node_id, root)

@@ -114,13 +114,17 @@ WALL_SPANS = (
     "train.feed", "train.dispatch", "train.wait", "train.fedavg",
     "exchange.fetch", "publish.encode", "publish.put", "publish.serialize",
     "publish.hash", "publish.checkpoint", "score.eval", "score.multikrum",
-    "merge", "chain.submit",
+    "merge", "chain.submit", "store.retain",
 )
 SCHEMAS["wall"] = {
     **{f"{s}_{k}": kind for s in WALL_SPANS
        for k, kind in (("s", "seconds"), ("n", "counter"))},
     "host_syncs": "counter",     # device values the host blocked on
     "h2d_bytes": "counter",      # bytes put on the device
+    # bytes a store node took into memory (puts, fetches, ingests) and let
+    # go (retain, gc): their difference is what the store nodes hold
+    "store_bytes_in": "counter",
+    "store_bytes_dropped": "counter",
 }
 
 COUNTER_KINDS = ("counter", "seconds")

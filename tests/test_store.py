@@ -78,6 +78,35 @@ def test_integrity_verified_on_peer_fetch():
     a, b = net.add_node("a"), net.add_node("b")
     cid = a.put(_tree())
     # corrupt a's block
-    a._blocks[cid] = [b"corrupted"]
+    a._blocks[cid] = b"corrupted"
     with pytest.raises((IOError, KeyError)):
         b.get_bytes(cid)
+
+
+@pytest.mark.parametrize("on_disk", [False, True])
+def test_retain_bounds_memory_and_keeps_delta_bases(tmp_path, on_disk):
+    """``retain`` keeps the named CIDs and, with ``with_bases``, the delta
+    chain under them; it drops the rest from memory (pins and decoded
+    models too), while files under ``root`` stay readable."""
+    from repro.config import FedConfig
+    from repro.core import wire
+
+    root = str(tmp_path) if on_disk else None
+    net = StoreNetwork()
+    a = net.add_node("a", root)
+    fed = FedConfig(compression="int8-delta")
+    vec = np.linspace(-1, 1, 4096).astype(np.float32)
+    env0 = wire.encode_update({"w": vec}, fed)
+    base = a.put(env0.to_store())
+    dm = a.get_decoded(base, a.wire_decoder())
+    env1 = wire.encode_update({"w": vec * 1.5}, fed, base=(base, dm.vec()))
+    top = a.put(env1.to_store())
+    stale = a.put(_tree())
+    assert a.retain({top}, with_bases=True) == [stale]
+    assert a.held_bytes() == sum(len(a.read_local(c)) for c in (base, top))
+    a.get_decoded(top, a.wire_decoder()).vec()
+    assert a.has_decoded(base)
+    dropped = set(a.retain({top}))
+    assert dropped == {base}
+    assert not a.has_decoded(base) and base not in a._pins
+    assert bool(a.has(base)) == on_disk

@@ -162,3 +162,103 @@ def test_sync_straggler_deferred_and_rejoins():
                        and l["sender"] == slow.silo_id]
     assert deferred_events, "straggler never submitted"
     assert orch.ledger.verify()
+
+
+# --------------------------------------------------------------------------- #
+# Store retention: each node holds what the protocol can still read
+# --------------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("mode,compression,keyframe_every", [
+    ("sync", "int8", 0),
+    ("sync", "int8-delta", 2),
+    ("async", "int8", 0),
+])
+def test_store_holds_what_the_protocol_can_read(mode, compression,
+                                                keyframe_every):
+    """Six rounds, three silos. From round 2 on, a node holds at most each
+    owner's models of two rounds (Sync) or its latest and still unscored
+    ones (Async), the delta bases those need back to their keyframe, and
+    its own latest checkpoint: 2n + 1 + n(k - 1) CIDs, whatever the round.
+    Sync int8 envelopes all have one size, so there the bytes are flat."""
+    from repro.config import ObsConfig
+    from repro.core.orchestrator import live_cids
+    from repro.obs import wall
+
+    rounds, n = 6, 3
+    fed = _fed(rounds=rounds, mode=mode, compression=compression,
+               keyframe_every=keyframe_every, obs=ObsConfig(wall=True))
+    orch = build_image_experiment(CNN, fed, n_train=120, n_test=60, seed=9)
+    bound = 2 * n + 1 + n * max(keyframe_every - 1, 0)
+    held, dropped = [], []
+    mark = orch._mark_round
+
+    def on_round(r, silo_id=None):
+        mark(r, silo_id)
+        held.append((r, [s.store.held_bytes() for s in orch.silos],
+                     [len(s.store._blocks) for s in orch.silos]))
+
+    retain = type(orch.silos[0]).retain
+
+    def recorded(silo):
+        out = retain(silo)
+        # nothing dropped lingers as blocks or in the decoded cache
+        assert not any(silo.store.has_decoded(c) or c in silo.store._blocks
+                       for c in out)
+        dropped.extend(out)
+        return out
+
+    orch._mark_round = on_round
+    for s in orch.silos:
+        s.retain = lambda s=s: recorded(s)
+    orch.run(rounds)
+    later = [h for h in held if h[0] >= 2]
+    assert later and all(max(counts) <= bound for _, _, counts in later)
+    if mode == "sync" and compression == "int8":
+        assert len({tuple(b) for _, b, _ in later}) == 1
+    # what the stores hold is what the counters say they took in, less what
+    # they let go; without retention they would hold all they took in
+    t = wall.totals()
+    held_now = sum(s.store.held_bytes() for s in orch.silos)
+    assert t["store_bytes_in"] - t["store_bytes_dropped"] == held_now
+    assert t["store_bytes_dropped"] > held_now
+    # every CID the contract can hand out, with its delta bases, still
+    # reaches every silo
+    for s in orch.silos:
+        for cid in live_cids(s.contract):
+            assert s.fetch(cid).vec().shape[0] > 0
+    assert dropped and orch.ledger.verify()
+
+
+def test_retention_keeps_provider_records_true_and_restores_after_a_kill():
+    """Kill and restart over a WAN fabric with gossip replicas and prefetch:
+    every provider record the fabric keeps names a node that still holds
+    the CID, and the killed silo restores from its latest checkpoint."""
+    from repro.config import FaultScenario, NetConfig
+
+    scenarios = (
+        FaultScenario(action="kill", node="silo2", round=2, when="train"),
+        FaultScenario(action="restart", node="silo2", round=3, when="train"),
+    )
+    fed = _fed(n_silos=4, clients_per_silo=1, rounds=4, compression="int8",
+               round_deadline_s=3.0, scorer_deadline_s=2.0,
+               net=NetConfig(preset="wan-heterogeneous", replication_factor=1,
+                             prefetch=True, scenarios=scenarios))
+    specs = [SiloSpec(extra_train_delay=1.0 + 0.05 * i) for i in range(4)]
+    orch = build_image_experiment(CNN, fed, n_train=240, n_test=120,
+                                  silo_specs=specs, seed=1)
+    for s in orch.silos:
+        s.time_scale = 0.0
+    orch.run(4)
+    fab = orch.fabric
+    for cid in list(fab._providers):
+        for nid in fab.providers(cid):
+            node = orch.network.nodes.get(nid)
+            assert node is None or node.has(cid), (nid, cid)
+    victim = orch.silos[2]
+    assert victim.alive and victim.rounds_done == 4
+    saved = jax.tree.map(np.asarray, victim.cluster.params)
+    victim.cluster.params = victim.cluster.model.init(jax.random.PRNGKey(99))
+    victim.restore_from(victim.last_checkpoint)
+    for a, b in zip(jax.tree_util.tree_leaves(saved),
+                    jax.tree_util.tree_leaves(victim.cluster.params)):
+        np.testing.assert_array_equal(a, np.asarray(b))
