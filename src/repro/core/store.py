@@ -35,10 +35,12 @@ import os
 import threading
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.obs import wall
 from repro.obs.metrics import StatsView
@@ -46,24 +48,44 @@ from repro.obs.metrics import StatsView
 DECODED_CACHE_MAX = 64  # CIDs kept in each node's decoded-model cache
 CID_BLOCK = 1 << 20     # bytes a CID hashes per block (IPFS's chunk size)
 HASH_THREADS = min(8, os.cpu_count() or 1)  # hashlib releases the GIL
+# payloads whose device leaves hold fewer bytes keep the host path: a few
+# small transfers cost them less than the pack's dispatch and its compile
+PACK_MIN_BYTES = CID_BLOCK
+
+# a payload's bytes, as the store holds them: a 1-D ``uint8`` array where
+# the image came from the device (``serialize_pytree``)
+Block = Union[bytes, bytearray, np.ndarray]
+
+
+def is_block(obj) -> bool:
+    """Whether ``obj`` is a payload's bytes rather than a pytree."""
+    return isinstance(obj, (bytes, bytearray)) or (
+        isinstance(obj, np.ndarray) and obj.dtype == np.uint8
+        and obj.ndim == 1)
 
 
 # --------------------------------------------------------------------------- #
 # Deterministic pytree codec
 # --------------------------------------------------------------------------- #
 
-def serialize_pytree(tree) -> bytearray:
-    """JSON header, then every leaf's raw bytes, written once into one
-    buffer (a payload is never copied twice on its way into the store)."""
+def serialize_pytree(tree) -> Block:
+    """The payload image: the JSON header's length (8 bytes, little-endian),
+    the header, then every leaf's raw bytes in ``tree_flatten`` order.
+
+    Where the leaves on the device hold ``PACK_MIN_BYTES`` or more, the image
+    is built on the device and crosses to the host in one transfer
+    (``_device_image``), landing as a read-only ``uint8`` array that nothing
+    copies again. Otherwise each leaf comes to the host and is written once
+    into one ``bytearray``. Both give the same bytes."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
+    on_device = [l for l in leaves if isinstance(l, jax.Array)]
+    if (sum(l.nbytes for l in on_device) >= PACK_MIN_BYTES
+            and all(l.dtype.itemsize in (1, 2, 4) for l in on_device)):
+        arrs = [l if isinstance(l, jax.Array) else np.asarray(l)
+                for l in leaves]
+        return _device_image(_header(tree, treedef, arrs), arrs)
     arrs = [wall.to_host(l) for l in leaves]
-    header = {
-        "treedef": str(treedef),
-        "leaves": [{"dtype": str(a.dtype), "shape": list(a.shape)} for a in arrs],
-        "paths": [_path_str(p) for p, _ in
-                  jax.tree_util.tree_flatten_with_path(tree)[0]],
-    }
-    hb = json.dumps(header, sort_keys=True).encode()
+    hb = _header(tree, treedef, arrs)
     out = bytearray(8 + len(hb) + sum(a.nbytes for a in arrs))
     out[:8] = len(hb).to_bytes(8, "little")
     off = 8 + len(hb)
@@ -74,6 +96,104 @@ def serialize_pytree(tree) -> bytearray:
             np.uint8)
         off += a.nbytes
     return out
+
+
+def _header(tree, treedef, arrs) -> bytes:
+    return json.dumps({
+        "treedef": str(treedef),
+        "leaves": [{"dtype": str(a.dtype), "shape": list(a.shape)}
+                   for a in arrs],
+        "paths": [_path_str(p) for p, _ in
+                  jax.tree_util.tree_flatten_with_path(tree)[0]],
+    }, sort_keys=True).encode()
+
+
+def _device_image(hb: bytes, arrs) -> np.ndarray:
+    """The image of ``serialize_pytree`` built on the device as 32-bit
+    little-endian words and brought to the host in one transfer; the host
+    views the words' first bytes. The header and the host leaves go up as
+    words of their bytes (the image's other bytes are data too), so the
+    pack compiles once per pytree structure, not once per value."""
+    head = len(hb).to_bytes(8, "little") + hb
+    layout, host_words, dev = [], [], []
+    off = 0
+    for seg in [head, *arrs]:
+        if isinstance(seg, jax.Array):
+            layout.append((off, seg.nbytes, True))
+            dev.append(seg)
+            off += seg.nbytes
+            continue
+        if not isinstance(seg, bytes):
+            seg = np.ascontiguousarray(seg).tobytes()
+        padded = bytes(off % 4) + seg
+        host_words.append(np.frombuffer(
+            padded + bytes(-len(padded) % 4), "<u4"))
+        layout.append((off, len(seg), False))
+        off += len(seg)
+    words = _pack(tuple(layout), _n_words(off), host_words, dev)
+    image = np.asarray(words).view(np.uint8)[:off]   # the one transfer
+    del words   # the device copy goes as soon as its host copy lands
+    wall.count("host_syncs")
+    wall.count("d2h_bytes", _n_words(off) * 4)
+    return image
+
+
+def _words(x) -> jax.Array:
+    """``x``'s raw bytes as little-endian 32-bit words, the last one padded
+    with zeros. Narrow elements combine across a swapped or transposed
+    axis: an axis of 2 or 4 elements in the lanes would be padded to 128 in
+    the TPU's layout."""
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.uint8)
+    size = x.dtype.itemsize
+    if size == 4:
+        return lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+    per = 4 // size                  # elements in a word
+    u = lax.bitcast_convert_type(x, jnp.uint8 if size == 1 else jnp.uint16)
+    n = u.size
+    if u.ndim >= 2 and u.shape[-1] % per == 0:
+        # a word's elements are neighbours in a row
+        t = jnp.swapaxes(u.reshape(-1, u.shape[-1] // per, per), 1, 2)
+        parts = [t[:, b, :] for b in range(per)]
+    else:
+        # row i of ``u`` holds words i*_LANES .. (i+1)*_LANES - 1
+        u = jnp.pad(u.reshape(-1), (0, -n % (per * _LANES)))
+        t = u.reshape(-1, per * _LANES).T.reshape(_LANES, per, -1)
+        parts = [t[:, b, :].T for b in range(per)]
+    w = parts[0].astype(jnp.uint32)
+    for b in range(1, per):
+        w = w | (parts[b].astype(jnp.uint32) << (8 * size * b))
+    return w.reshape(-1)[:_n_words(n * size)]
+
+
+def _pack_words(layout, n_words, host_words, dev):
+    """The image's words: each segment's words shifted to its byte offset
+    and OR-ed in (segments meet only inside the words they share, where each
+    is zero in the others' bytes)."""
+    img = jnp.zeros((n_words,), jnp.uint32)
+    host_words, dev = iter(host_words), iter(dev)
+    for off, nbytes, on_device in layout:
+        if on_device:
+            w, r = _words(next(dev)), off % 4
+            if r:
+                w = jnp.concatenate([w, jnp.zeros((1,), jnp.uint32)])
+                w = (w << 8 * r) | jnp.concatenate(
+                    [jnp.zeros((1,), jnp.uint32), w[:-1]]) >> (32 - 8 * r)
+                w = w[:_n_words(r + nbytes)]
+        else:
+            w = next(host_words)
+        k = off // 4
+        img = lax.dynamic_update_slice(
+            img, lax.slice(img, (k,), (k + w.shape[0],)) | w, (k,))
+    return img
+
+
+def _n_words(nbytes: int) -> int:
+    return -(-nbytes // 4)
+
+
+_LANES = 128
+_pack = jax.jit(_pack_words, static_argnums=(0, 1))
 
 
 def _path_str(path) -> str:
@@ -129,7 +249,7 @@ class StoreNode:
         self.network: Optional["StoreNetwork"] = None
         # cid -> its bytes, one buffer shared with the decoded models that
         # view it (never a second copy of a payload)
-        self._blocks: Dict[str, bytes] = {}
+        self._blocks: Dict[str, Block] = {}
         self._pins: set = set()
         self._peers: List["StoreNode"] = []
         self._lock = threading.Lock()
@@ -171,14 +291,19 @@ class StoreNode:
 
     # -- API ---------------------------------------------------------------- #
     def put(self, obj, *, pin: bool = True) -> str:
+        """Store ``obj``, a pytree or a payload's bytes (a ``Block``:
+        ``bytes``, ``bytearray`` or a 1-D ``uint8`` array, as
+        ``serialize_pytree`` returns), and return its CID."""
         with wall.span("publish.put", silo=self.node_id):
             return self._put(obj, pin)
 
     def _put(self, obj, pin: bool) -> str:
         data = obj
-        if not isinstance(obj, (bytes, bytearray)):
+        if not is_block(obj):
             with wall.span("publish.serialize", silo=self.node_id):
                 data = serialize_pytree(obj)
+            if isinstance(data, np.ndarray):
+                wall.count("packed_puts")
         with wall.span("publish.hash", silo=self.node_id):
             cid = compute_cid(data)
         with self._lock:
@@ -199,7 +324,7 @@ class StoreNode:
         return cid in self._blocks or (
             self.root and os.path.exists(os.path.join(self.root, cid)))
 
-    def _hold(self, cid: str, data: bytes) -> None:
+    def _hold(self, cid: str, data: Block) -> None:
         """Keep ``data`` as ``cid``'s block (lock held); new bytes count in."""
         if cid not in self._blocks:
             wall.count("store_bytes_in", len(data))
@@ -210,7 +335,7 @@ class StoreNode:
         with self._lock:
             return sum(len(d) for d in self._blocks.values())
 
-    def read_local(self, cid: str) -> Optional[bytes]:
+    def read_local(self, cid: str) -> Optional[Block]:
         """Local blocks / disk only — never touches the network."""
         with self._lock:
             if cid in self._blocks:
@@ -222,7 +347,7 @@ class StoreNode:
                     return f.read()
         return None
 
-    def serve_bytes(self, cid: str) -> Optional[bytes]:
+    def serve_bytes(self, cid: str) -> Optional[Block]:
         """Serve a block set to a remote peer (counts egress accounting)."""
         data = self.read_local(cid)
         if data is not None:
@@ -231,7 +356,7 @@ class StoreNode:
                 self.stats["bytes_out"] += len(data)
         return data
 
-    def ingest(self, cid: str, data: bytes, *, prefetched: bool = False):
+    def ingest(self, cid: str, data: Block, *, prefetched: bool = False):
         """Store pushed/fetched bytes locally (gossip replica or prefetch
         landing). Verifies content addressing; no-op if already present."""
         if compute_cid(data) != cid:
@@ -256,7 +381,7 @@ class StoreNode:
             t, self._pending_net_time = self._pending_net_time, 0.0
         return t
 
-    def get_bytes(self, cid: str) -> bytes:
+    def get_bytes(self, cid: str) -> Block:
         data = self.read_local(cid)
         if data is not None:
             with self._lock:
@@ -279,7 +404,7 @@ class StoreNode:
                 return data
         raise KeyError(f"CID {cid} not found on {self.node_id} or peers")
 
-    def _fetch_via_fabric(self, cid: str, fab) -> bytes:
+    def _fetch_via_fabric(self, cid: str, fab) -> Block:
         """Pull over the WAN fabric: nearest reachable replica, integrity
         check, link-time charge, replica/reroute accounting."""
         from repro.net.fabric import UnreachableError

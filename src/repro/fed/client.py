@@ -116,6 +116,8 @@ class Client:
             if w.enabled:
                 w.count("h2d_bytes", lr.nbytes + sum(
                     a.nbytes for a in batch.values()))
+        if w.enabled:
+            w.count("d2h_bytes", sum(l.nbytes for l in losses))
         with w.span("train.wait"):
             # float64, as the Python floats of a per-step read were
             losses = np.asarray(jax.device_get(losses), np.float64)

@@ -121,6 +121,10 @@ SCHEMAS["wall"] = {
        for k, kind in (("s", "seconds"), ("n", "counter"))},
     "host_syncs": "counter",     # device values the host blocked on
     "h2d_bytes": "counter",      # bytes put on the device
+    "d2h_bytes": "counter",      # bytes brought to the host
+    # store puts whose payload image was built on the device and crossed
+    # to the host in one transfer (``store.serialize_pytree``)
+    "packed_puts": "counter",
     # bytes a store node took into memory (puts, fetches, ingests) and let
     # go (retain, gc): their difference is what the store nodes hold
     "store_bytes_in": "counter",

@@ -14,7 +14,8 @@ measures the host. Where the work happens, the program opens a span by name
     metadata; the per-step spans of local training carry none.
   * ``totals()`` is a plain dict over the ``wall`` schema of
     ``repro.obs.metrics`` (``<span>_s`` self seconds, ``<span>_n`` calls,
-    ``host_syncs``, ``h2d_bytes``), so two snapshots subtract (``diff``).
+    ``host_syncs``, ``h2d_bytes``, ``d2h_bytes``, ...), so two snapshots
+    subtract (``diff``).
     The names are declared there: an undeclared span raises.
 
 The wall is process-wide and off by default. Off, the active object is the
@@ -152,7 +153,9 @@ def diff(before: Dict[str, float], after: Dict[str, float]
 
 def to_host(x, dtype=None) -> np.ndarray:
     """``np.asarray(x, dtype)``. Where ``x`` is a device array the host
-    blocks on it and copies it back: one ``host_syncs``."""
+    blocks on it and copies it back: one ``host_syncs``, and its bytes in
+    ``d2h_bytes``."""
     if _active.enabled and isinstance(x, jax.Array):
         _active.count("host_syncs")
+        _active.count("d2h_bytes", x.nbytes)
     return np.asarray(x, dtype)

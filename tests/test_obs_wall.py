@@ -89,12 +89,14 @@ def test_counters_and_the_difference_of_two_snapshots(monkeypatch):
     d = wall.diff(before, after)
     assert d["host_syncs"] == 2 and d["h2d_bytes"] == 28
     assert d["train.wait_n"] == 1 and d["train.feed_n"] == 0
-    # to_host counts device values only
+    # to_host counts device values only, and their bytes as they cross
     wall.to_host(np.ones(3))
     assert wall.totals()["host_syncs"] == after["host_syncs"]
+    assert wall.totals()["d2h_bytes"] == 0
     host = wall.to_host(jax.numpy.ones(3), np.float64)
     assert host.dtype == np.float64
     assert wall.totals()["host_syncs"] == after["host_syncs"] + 1
+    assert wall.totals()["d2h_bytes"] == 3 * 4
 
 
 def test_a_span_is_an_annotation_with_its_metadata(monkeypatch):

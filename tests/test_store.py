@@ -110,3 +110,68 @@ def test_retain_bounds_memory_and_keeps_delta_bases(tmp_path, on_disk):
     assert dropped == {base}
     assert not a.has_decoded(base) and base not in a._pins
     assert bool(a.has(base)) == on_disk
+
+
+def _checkpoint_tree():
+    rng = np.random.default_rng(0)
+    return {"params": {
+        "embed": jnp.asarray(rng.normal(size=(512, 1024)), jnp.bfloat16),
+        "mlp": jnp.asarray(rng.normal(size=(64, 96)), jnp.bfloat16),
+        "norm": jnp.asarray(rng.normal(size=(1024,)), jnp.float32)},
+        "round": np.asarray(7)}
+
+
+def _int8_tree():
+    rng = np.random.default_rng(1)
+    return {"q": jnp.asarray(rng.integers(-127, 128, (1024, 1030)), jnp.int8),
+            "scales": jnp.asarray(rng.random(1030), jnp.float32)}
+
+
+def _odd_tree():
+    rng = np.random.default_rng(2)
+    return {"w": jnp.asarray(rng.normal(size=(1 << 19) + 3), jnp.bfloat16),
+            "b": jnp.asarray(rng.integers(-9, 9, 5), jnp.int8),
+            "flags": jnp.asarray([True, False, True])}
+
+
+@pytest.mark.parametrize("make", [_checkpoint_tree, _int8_tree, _odd_tree],
+                         ids=["checkpoint", "int8", "odd-length"])
+def test_a_device_payload_is_packed_into_the_host_image(make):
+    """A payload whose leaves live on the device is built on the device and
+    crosses in one transfer: the same bytes and CID as the host path, held
+    read-only and viewed by the decoded leaves, one packed put, and one
+    compile per pytree structure whatever the values."""
+    import jax
+    from repro.core import store
+    from repro.obs import wall
+
+    tree = make()
+    host = jax.tree.map(np.asarray, tree)
+    want = serialize_pytree(host)
+    assert isinstance(want, bytearray)       # host leaves: the host path
+    node = StoreNode("n")
+    wall.switch(True)
+    try:
+        cid = node.put(tree)
+        t = wall.totals()
+    finally:
+        wall.switch(False)
+    block = node.read_local(cid)
+    assert isinstance(block, np.ndarray) and block.dtype == np.uint8
+    assert bytes(block) == bytes(want)
+    assert cid == compute_cid(want)
+    assert not block.flags.writeable and block.flags.c_contiguous
+    flat = deserialize_pytree(block)
+    for got, leaf in zip(flat.values(), jax.tree_util.tree_leaves(host)):
+        assert np.shares_memory(got, block)
+        np.testing.assert_array_equal(got, leaf)
+    # one put packed, one transfer of the image's 32-bit words
+    assert t["packed_puts"] == 1 and t["host_syncs"] == 1
+    assert t["d2h_bytes"] == -(-len(block) // 4) * 4
+    # two checkpoints that differ in a host value only: one compile
+    n0 = store._pack._cache_size()
+    renamed = {"fresh": tree}
+    a = serialize_pytree({**renamed, "round": np.asarray(1)})
+    b = serialize_pytree({**renamed, "round": np.asarray(2)})
+    assert store._pack._cache_size() == n0 + 1
+    assert bytes(a[:-8]) == bytes(b[:-8]) and bytes(a) != bytes(b)

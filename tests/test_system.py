@@ -113,6 +113,55 @@ def test_checkpoint_restart_resumes_from_cas():
     assert after["accuracy"] == pytest.approx(before["accuracy"], abs=1e-6)
 
 
+def _lm_federation(monkeypatch, pack_min_bytes):
+    """A sync qwen3-smoke federation of two rounds, every CID each store
+    node put recorded in order, with ``store.PACK_MIN_BYTES`` set."""
+    from repro.configs import get_smoke_config
+    from repro.core import store
+    from repro.core.builder import build_lm_experiment
+
+    monkeypatch.setattr(store, "PACK_MIN_BYTES", pack_min_bytes)
+    fed = _fed(clients_per_silo=1, compression="int8", scorer="loss",
+               scorer_deadline_s=0.0)
+    orch = build_lm_experiment(get_smoke_config("qwen3-1.7b"), fed,
+                               seq_len=16, batch_size=2, steps_per_epoch=2,
+                               stream_len=3000, seed=5)
+    puts = []
+    put = store.StoreNode.put
+
+    def recorded(node, obj, **kw):
+        cid = put(node, obj, **kw)
+        puts.append((node.node_id, cid))
+        return cid
+    monkeypatch.setattr(store.StoreNode, "put", recorded)
+    orch.run(fed.rounds)
+    return orch, puts
+
+
+def test_packed_checkpoints_restore_bit_for_bit_with_the_host_paths_cids(
+        monkeypatch):
+    """Checkpoints packed on the device: ``restore_from`` gives back the
+    silo's params bit for bit, and every payload of every round has the CID
+    the host path gives on the same seed."""
+    orch, packed = _lm_federation(monkeypatch, 0)
+    monkeypatch.undo()
+    _, host = _lm_federation(monkeypatch, float("inf"))   # the host path
+    # measured compute enters the simulated clock, so the order of the
+    # silos' puts within a round is the host's, not the seed's
+    assert sorted(packed) == sorted(host) and len(packed) == 12
+    silo = orch.silos[0]
+    block = silo.store.read_local(silo.last_checkpoint)
+    assert isinstance(block, np.ndarray)     # packed on the device
+    want = jax.tree.map(np.asarray, silo.cluster.params)
+    silo.cluster.params = silo.cluster.model.init(jax.random.PRNGKey(99))
+    silo.restore_from(silo.last_checkpoint)
+    got = jax.tree.map(np.asarray, silo.cluster.params)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
 def test_multikrum_sync_mode():
     fed = _fed(rounds=2, scorer="multikrum", agg_policy="top_k")
     orch = build_image_experiment(CNN, fed, n_train=600, n_test=200, seed=5)
