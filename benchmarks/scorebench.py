@@ -101,7 +101,7 @@ def main(quick: bool = True, out_path: str = "BENCH_scoring.json",
             seq_syncs[0] = 0
             out = []
             for flat in flats:
-                dm = wire.decode_flat(flat)
+                dm = wire.decode_store(flat)
                 params = ops.unflatten_pytree(dm.vec(), spec)
                 acc = 0.0
                 for i in range(0, n_test, bs):
@@ -117,7 +117,7 @@ def main(quick: bool = True, out_path: str = "BENCH_scoring.json",
 
         # -- batched: q8-direct ingest + one scan x vmap pass -------------- #
         def batched():
-            decoded = [wire.decode_flat(f) for f in flats]
+            decoded = [wire.decode_store(f) for f in flats]
             return scorebatch.score_round_batch(silo, decoded, spec,
                                                 method="accuracy")
 

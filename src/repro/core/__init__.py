@@ -10,5 +10,4 @@ orchestrator-- Sync / Async round engines with straggler & failure handling
 exchange    -- jittable cross-silo exchange over the 'pod' mesh axis
 wire        -- the one model-exchange codec (versioned ModelEnvelope:
                raw | int8 | int8-delta | topk-delta, base-chain resolution)
-compression -- legacy compression API (thin shims over wire)
 """

@@ -1,8 +1,7 @@
 """repro.core.wire — the single model exchange codec (``ModelEnvelope``).
 
 Every model that crosses a silo boundary — store puts, gossip replicas,
-prefetches, the legacy in-memory compression API — is encoded and decoded
-here, and nowhere else. An envelope is versioned and self-describing:
+prefetches — is encoded and decoded here, and nowhere else. An envelope is versioned and self-describing:
 
   method        payload                                     base chain
   ----------    ----------------------------------------    ----------
@@ -31,9 +30,8 @@ materializing the dequantized f32 delta.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,10 +63,8 @@ K_VEC = _kp("vec")
 
 _ARRAY_FIELDS = ("q", "scales", "tiles", "idx", "vals", "vec")
 
-# legacy compression-method names -> wire methods
-_METHOD_ALIASES = {"none": "raw", "raw": "raw", "int8": "int8",
-                   "int8-delta": "int8-delta", "topk": "topk-delta",
-                   "topk-delta": "topk-delta"}
+# FedConfig.compression values -> wire methods ("none" is its default)
+_METHOD_ALIASES = {"none": "raw", **{m: m for m in METHODS}}
 
 
 def resolve_method(compression: str) -> str:
@@ -175,7 +171,7 @@ def encode_vec(vec, method: str, *, base_vec=None, base_cid: str = "",
 
     Delta methods encode (vec - base_vec); without a base they fall back to
     a whole-model envelope (``int8-delta`` -> ``int8``) or a delta against
-    zeros (``topk-delta``, the legacy sparsify-the-model semantics)."""
+    zeros (``topk-delta``: the sparsified model itself)."""
     method = resolve_method(method)
     vec = jnp.asarray(vec, jnp.float32)
     n = int(vec.shape[0])
@@ -291,7 +287,7 @@ class DecodedModel:
         self.base_cid = base_cid or ""
         self._vec = vec
         self._resolver = resolver
-        if method is None:  # legacy construction sites: int8 payload or vec
+        if method is None:  # bare construction: int8 payload or vec
             method = "int8" if q is not None else "raw"
         self.method = method
 
@@ -332,13 +328,30 @@ class DecodedModel:
         return vec
 
 
+def stack_q8(models: Sequence[DecodedModel]) -> Tuple[np.ndarray, np.ndarray]:
+    """Whole-int8 models -> their payloads stacked on the host, (q int8
+    [K, Np], scales f32 [K, Np/QT]): the one place a round's peer payloads
+    are stacked for the device and counted as ``h2d_bytes`` (the kernel that
+    takes the stack moves it). The store's buffer views stack without a
+    copy of their own; models of two padded lengths raise ``ValueError``."""
+    if not all(m.is_q8 for m in models):
+        raise ValueError("stack_q8 takes whole-int8 models only")
+    lengths = sorted({int(m.q.shape[0]) for m in models})
+    if len(lengths) > 1:
+        raise ValueError(f"int8 payloads of padded lengths {lengths[0]} and "
+                         f"{lengths[-1]} do not stack")
+    q = np.stack([np.asarray(m.q) for m in models])
+    s = np.stack([np.asarray(m.scales) for m in models])
+    wall.count("h2d_bytes", q.nbytes + s.nbytes)
+    return q, s
+
+
 def decode_store(flat: Dict[str, np.ndarray],
                  resolver: Optional[Callable] = None) -> DecodedModel:
     """Store payload (keystr -> array dict) -> DecodedModel.
 
-    Handles v1 ``__wire__`` envelopes, the legacy pre-wire int8 envelope
-    (``{"__method__": "int8", "q", "scales", "n"}``), and raw parameter
-    payloads (flattened to one f32 vector in jax tree order)."""
+    Handles v1 ``__wire__`` envelopes and raw parameter payloads (flattened
+    to one f32 vector in jax tree order)."""
     if K_WIRE in flat:
         version = int(np.asarray(flat[K_WIRE]))
         if version > WIRE_VERSION:
@@ -363,68 +376,8 @@ def decode_store(flat: Dict[str, np.ndarray],
                                 method="topk-delta", base_cid=base_cid,
                                 resolver=resolver)
         raise ValueError(f"unknown wire method {method!r} in envelope")
-    legacy = flat.get(K_METHOD)
-    if legacy is not None and str(np.asarray(legacy)) == "int8":
-        return DecodedModel(int(np.asarray(flat[K_N])),
-                            q=np.asarray(flat[K_Q]),
-                            scales=np.asarray(flat[K_SCALES]))
     if not flat:
         return DecodedModel(0, vec=jnp.zeros((0,), jnp.float32))
     vec = jnp.concatenate([jnp.ravel(jnp.asarray(v)).astype(jnp.float32)
                            for v in flat.values()])
     return DecodedModel(int(vec.shape[0]), vec=vec)
-
-
-def decode_flat(flat: Dict[str, np.ndarray]) -> DecodedModel:
-    """Resolver-less decode (non-delta payloads / tests)."""
-    return decode_store(flat)
-
-
-def _envelope_from_store(flat: Dict) -> Optional[ModelEnvelope]:
-    """Parse a plain-key payload dict (pre-serialization form) back into an
-    envelope; None when it is not an envelope."""
-    if "__wire__" not in flat:
-        return None
-    g = lambda k: (jnp.asarray(flat[k]) if k in flat else None)
-    return ModelEnvelope(str(np.asarray(flat["__method__"])),
-                         int(np.asarray(flat["n"])),
-                         base_cid=(str(np.asarray(flat["base_cid"]))
-                                   if "base_cid" in flat else ""),
-                         q=g("q"), scales=g("scales"), tiles=g("tiles"),
-                         idx=g("idx"), vals=g("vals"), vec=g("vec"))
-
-
-# --------------------------------------------------------------------------- #
-# Legacy in-memory compression API (repro.core.compression delegates here)
-# --------------------------------------------------------------------------- #
-
-def compress_pytree(params, method: str = "int8", *, base=None,
-                    topk_frac: float = 0.01) -> Dict:
-    """Payload pytree for a params tree; delta-coded iff ``base`` is given."""
-    vec, _ = ops.flatten_pytree(params)
-    bvec = ops.flatten_pytree(base)[0] if base is not None else None
-    m = resolve_method(method)
-    if m == "int8" and bvec is not None:
-        m = "int8-delta"
-    # "__inline__": the base is supplied by the decompress caller, not a CID
-    return encode_vec(vec, m, base_vec=bvec, topk_frac=topk_frac,
-                      base_cid="__inline__" if bvec is not None else ""
-                      ).to_store()
-
-
-def decompress_pytree(payload: Dict, like, *, base=None):
-    """Inverse of ``compress_pytree``; delta payloads reconstruct against
-    ``base`` (or ``like`` when no base is passed, the legacy fallback)."""
-    env = _envelope_from_store(payload)
-    if env is None:
-        raise ValueError("not a wire envelope payload")
-    _, spec = ops.flatten_pytree(like)
-    bvec = None
-    if env.base_cid:  # delta vs a caller-supplied base (legacy: like)
-        bvec = ops.flatten_pytree(base if base is not None else like)[0]
-    return ops.unflatten_pytree(env.reconstruct(bvec), spec)
-
-
-def payload_bytes(payload) -> int:
-    """Total bytes of a payload pytree (envelope or raw params)."""
-    return sum(np.asarray(l).nbytes for l in jax.tree.leaves(payload))

@@ -12,9 +12,8 @@ from typing import List, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.wire import DecodedModel
+from repro.core.wire import DecodedModel, stack_q8
 from repro.kernels import ops
-from repro.obs import wall
 from repro.optim.fedopt import ServerOptimizer, make_server_optimizer
 
 
@@ -51,45 +50,26 @@ class SiloAggregator:
         """Merge peer models into the silo's flat f32 vector [n].
 
         weights[0] is the self-weight; weights[1:] align with ``peers``.
-        int8 peers are grouped by padded length and consumed by one fused
-        kernel call per group; f32 peers add their (cached) vectors."""
+        Whole-int8 peers go as one ``wire.stack_q8`` into one fused
+        ``wsum_q8`` call; other peers add their (cached) vectors."""
         if not peers:
             return own_vec
         w = np.asarray(weights, np.float64)
         w = (w / w.sum()).astype(np.float32)
         n = int(own_vec.shape[0])
         mixed = w[0] * own_vec
-        groups: dict = {}
-        f32_peers = []
-        for wi, p in zip(w[1:], peers):
-            if p.is_q8:
-                groups.setdefault(int(p.q.shape[0]), []).append((wi, p))
-            else:
-                f32_peers.append((wi, p))
-        for grp in groups.values():
+        q8 = [(wi, p) for wi, p in zip(w[1:], peers) if p.is_q8]
+        if q8:
             # payloads arrive as host arrays: stack there, move once
-            q = np.stack([np.asarray(p.q) for _, p in grp])
-            s = np.stack([np.asarray(p.scales) for _, p in grp])
-            wall.count("h2d_bytes", q.nbytes + s.nbytes)
-            gw = jnp.asarray(np.asarray([wi for wi, _ in grp], np.float32))
+            q, s = stack_q8([p for _, p in q8])
+            gw = jnp.asarray(np.asarray([wi for wi, _ in q8], np.float32))
             mixed = mixed + ops.weighted_sum_q8(q, s, gw, n)
-        for wi, p in f32_peers:
-            mixed = mixed + wi * p.vec()[:n]
+        for wi, p in zip(w[1:], peers):
+            if not p.is_q8:
+                mixed = mixed + wi * p.vec()[:n]
         delta = mixed - own_vec
         if self._opt_state is None:
             self._opt_state = self.server_opt.init(own_vec)
         new, self._opt_state = self.server_opt.apply(own_vec, delta,
                                                      self._opt_state)
         return new
-
-    def apply_cross_silo(self, own_params, peer_params: List,
-                         weights: List[float]):
-        """Pytree-facing wrapper over the flat-vector merge."""
-        if not peer_params:
-            return own_params
-        spec = ops.make_flatten_spec(own_params)
-        own_vec, _ = ops.flatten_pytree(own_params, spec)
-        peers = [DecodedModel(int(v.shape[0]), vec=v)
-                 for v, _ in (ops.flatten_pytree(p, spec) for p in peer_params)]
-        new_vec = self.apply_cross_silo_vec(own_vec, peers, weights)
-        return ops.unflatten_pytree(new_vec, spec)

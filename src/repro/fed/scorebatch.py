@@ -17,12 +17,11 @@ This engine restructures the whole score phase around two ideas:
     (scorer, round) score call).
 
   * **q8-direct ingest.** The stack is fed straight from the wire layer: a
-    round's packed int8 payloads are grouped by padded length and expanded
-    by the batched-dequant Pallas kernel (``ops.dequantize_batch``, oracle
-    ``ref.dequantize_rows``) into one ``[K, N]`` matrix — K separate f32
-    pytrees are never materialized. Raw / delta envelopes contribute their
-    (cached) reconstructed vectors; ``ops.unflatten_batch`` then slices the
-    matrix into the stacked pytree against the round's cached flatten spec.
+    whole-int8 round's payloads are stacked once (``wire.stack_q8``) and
+    expanded by the batched-dequant Pallas kernel (``ops.dequantize_batch``,
+    oracle ``ref.dequantize_rows``) straight into the stacked pytree's
+    leaves — K separate f32 pytrees are never materialized. Any other round
+    stacks each model's (cached) reconstructed vector.
 
 ``Cluster.evaluate`` shares the same machinery with K=1, which also moves
 its per-batch accumulation inside jit (no per-batch host syncs for
@@ -38,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.wire import stack_q8
 from repro.kernels import ops
 from repro.obs import wall
 
@@ -59,34 +59,6 @@ _PREPARED: "OrderedDict" = OrderedDict()
 # --------------------------------------------------------------------------- #
 # Wire -> stacked models (the q8-direct ingest path)
 # --------------------------------------------------------------------------- #
-
-def stack_decoded_vecs(decoded: Sequence, n: int):
-    """A round's ``DecodedModel``s -> one [K, n] f32 matrix.
-
-    int8 payloads are grouped by padded length and expanded by ONE batched
-    dequant kernel call per group; raw and (resolved) delta envelopes
-    contribute their cached vectors. No K separate f32 pytrees."""
-    K = len(decoded)
-    if K == 0:
-        return jnp.zeros((0, n), jnp.float32)
-    rows: List = [None] * K
-    groups: Dict[int, List[int]] = {}
-    for i, d in enumerate(decoded):
-        if getattr(d, "is_q8", False):
-            groups.setdefault(int(d.q.shape[0]), []).append(i)
-        else:
-            rows[i] = jnp.asarray(d.vec(), jnp.float32)[:n]
-    for idxs in groups.values():
-        q = np.stack([np.asarray(decoded[i].q) for i in idxs])
-        s = np.stack([np.asarray(decoded[i].scales) for i in idxs])
-        wall.count("h2d_bytes", q.nbytes + s.nbytes)
-        mat = ops.dequantize_batch(q, s, n)
-        if len(idxs) == K:  # uniform int8 round (the default compression's
-            return mat      # hot path): the batch IS the answer, no restack
-        for j, i in enumerate(idxs):
-            rows[i] = mat[j]
-    return jnp.stack(rows)
-
 
 def _leaf_dtype(spec):
     """The one dtype every leaf of ``spec`` has, else f32."""
@@ -121,16 +93,12 @@ def stack_decoded(decoded: Sequence, spec):
 
     One jitted program runs from the payloads to the stacked leaves, so no
     f32 [K, N] matrix is left beside them (4 GB for two 516M-parameter
-    models): a uniform int8 round dequantizes inside it, and any other
-    round reconstructs each model's vector, casts it to the leaf dtype
-    before the next one is built (a cast per element, so the result is the
-    same) and stacks them inside it."""
-    if (decoded and all(getattr(d, "is_q8", False) for d in decoded)
-            and len({int(d.q.shape[0]) for d in decoded}) == 1):
-        q = np.stack([np.asarray(d.q) for d in decoded])
-        s = np.stack([np.asarray(d.scales) for d in decoded])
-        wall.count("h2d_bytes", q.nbytes + s.nbytes)
-        return _dequantize_stacked(q, s, spec)
+    models): a whole-int8 round dequantizes its ``wire.stack_q8`` inside
+    it, and any other round reconstructs each model's vector, casts it to
+    the leaf dtype before the next one is built (a cast per element, so the
+    result is the same) and stacks them inside it."""
+    if decoded and all(d.is_q8 for d in decoded):
+        return _dequantize_stacked(*stack_q8(decoded), spec)
     n = ops.spec_length(spec)
     dtype = _leaf_dtype(spec)
     return _stack_rows(tuple(jnp.asarray(d.vec(), jnp.float32)[:n].astype(dtype)

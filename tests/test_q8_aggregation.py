@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import compression
-from repro.core.compression import DecodedModel, decode_flat
+from repro.core import wire
+from repro.core.wire import DecodedModel
 from repro.core.scoring import multikrum_scores_for_decoded
 from repro.core.store import StoreNode
 from repro.kernels import ops, ref
@@ -137,9 +137,7 @@ def test_flatten_spec_is_cached_per_config():
 # --------------------------------------------------------------------------- #
 
 def _int8_envelope(vec):
-    q, s, n = ops.quantize(vec)
-    return {"__method__": np.asarray("int8"), "q": np.asarray(q),
-            "scales": np.asarray(s), "n": np.asarray(n)}
+    return wire.encode_vec(vec, "int8").to_store()
 
 
 def test_store_decodes_once_for_k_scorers():
@@ -149,7 +147,7 @@ def test_store_decodes_once_for_k_scorers():
     vec = jnp.arange(5000, dtype=jnp.float32) / 5000.0
     cid = node.put(_int8_envelope(vec))
     k = 5
-    decoded = [node.get_decoded(cid, decode_flat) for _ in range(k)]
+    decoded = [node.get_decoded(cid, wire.decode_store) for _ in range(k)]
     assert node.stats["decodes"] == 1
     assert node.stats["decode_hits"] == k - 1
     assert all(d is decoded[0] for d in decoded)  # one object, zero copies
@@ -165,11 +163,11 @@ def test_decoded_cache_is_bounded():
     cids = [node.put({"x": np.full((8,), float(i), np.float32)})
             for i in range(store_mod.DECODED_CACHE_MAX + 5)]
     for c in cids:
-        node.get_decoded(c, decode_flat)
+        node.get_decoded(c, wire.decode_store)
     assert len(node._decoded) == store_mod.DECODED_CACHE_MAX
 
 
-def test_decode_flat_exact_keys_param_named_q():
+def test_decode_store_exact_keys_param_named_q():
     """Regression: a raw model with params literally named 'q'/'scales'/'n'
     must not be mistaken for an int8 envelope (the old substring matching
     against keystr paths did exactly that)."""
@@ -178,18 +176,18 @@ def test_decode_flat_exact_keys_param_named_q():
               "n": np.asarray([9.0], np.float32)}
     node = StoreNode("n")
     cid = node.put(params)
-    dm = node.get_decoded(cid, decode_flat)
+    dm = node.get_decoded(cid, wire.decode_store)
     assert not dm.is_q8
     # leaf order is jax tree flatten order (sorted keys: n, q, scales)
     want = np.concatenate([params["n"], params["q"], params["scales"]])
     np.testing.assert_array_equal(np.asarray(dm.vec()), want)
 
 
-def test_decode_flat_int8_envelope_roundtrip():
+def test_decode_store_int8_envelope_roundtrip():
     vec = jax.random.normal(jax.random.PRNGKey(0), (7000,)) * 3.0
     node = StoreNode("n")
     cid = node.put(_int8_envelope(vec))
-    dm = node.get_decoded(cid, decode_flat)
+    dm = node.get_decoded(cid, wire.decode_store)
     assert dm.is_q8 and dm.n == 7000
     amax = float(jnp.max(jnp.abs(vec)))
     assert float(jnp.max(jnp.abs(dm.vec() - vec))) <= amax / 127.0 * 0.51 + 1e-6
@@ -205,6 +203,46 @@ def test_multikrum_for_decoded_uses_fused_path():
     f32 = multikrum_scores_for_decoded(
         [DecodedModel(n, vec=x[i]) for i in range(m)], 2)
     np.testing.assert_allclose(fused, f32, rtol=0.05, atol=0.05)
+
+
+def test_multikrum_for_decoded_mixed_round_matches_batched_dequant():
+    """A round of int8 and raw models stacks each model's vector; its int8
+    rows (``ops.dequantize``) equal the batched dequant's bit for bit, so
+    the scores are those of the batched ingest."""
+    m, n = 4, 6000
+    x, q, s = _quantized_rows(m, n, seed=5)
+    decoded = [DecodedModel(n, q=q[i], scales=s[i]) for i in (0, 1, 3)]
+    decoded.insert(2, DecodedModel(n, vec=x[2]))
+    got = multikrum_scores_for_decoded(decoded, 2)
+    rows = ops.dequantize_batch(q, s, n)
+    want = ops.multikrum_scores(rows.at[2].set(x[2]), 2)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  -np.asarray(want, np.float32))
+
+
+def test_merge_of_an_int8_and_a_raw_peer(monkeypatch):
+    """The cross-silo merge adds a whole-int8 peer through one wsum_q8 call
+    and a raw peer through its vector."""
+    from repro.fed.aggregator import SiloAggregator
+    n = 5000
+    x, q, s = _quantized_rows(3, n, seed=9)
+    own, raw = x[0], x[2]
+    peers = [DecodedModel(n, q=q[1], scales=s[1]),
+             DecodedModel(n, vec=raw)]
+    calls = []
+    wsum = ops.weighted_sum_q8
+
+    def counted(q, scales, w, n=None, force="auto"):
+        calls.append(q.shape)
+        return wsum(q, scales, w, n, force)
+    monkeypatch.setattr(ops, "weighted_sum_q8", counted)
+    got = SiloAggregator("s0").apply_cross_silo_vec(own, peers,
+                                                    [1.0, 2.0, 1.0])
+    assert calls == [(1, q.shape[1])]
+    want = (0.25 * own + 0.5 * ops.dequantize(q[1], s[1], n, force="ref")
+            + 0.25 * raw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-5)
 
 
 def test_e2e_int8_multikrum_round_decodes_once(tmp_path):

@@ -41,7 +41,7 @@ def _peer_vecs(model, k, seed=0):
 
 def _decode(env):
     """Envelope -> DecodedModel through the real store codec roundtrip."""
-    return wire.decode_flat(deserialize_pytree(serialize_pytree(
+    return wire.decode_store(deserialize_pytree(serialize_pytree(
         env.to_store())))
 
 
@@ -101,10 +101,12 @@ def test_stack_decoded_mixed_wire_matches_vecs(model):
     n = ops.spec_length(spec)
     decoded = [_decode(wire.encode_vec(v, m))
                for v, m in zip(vecs, ("raw", "int8", "int8", "raw"))]
-    mat = scorebatch.stack_decoded_vecs(decoded, n)
-    assert mat.shape == (4, n)
+    stacked = scorebatch.stack_decoded(decoded, spec)
     for i, d in enumerate(decoded):
-        np.testing.assert_allclose(np.asarray(mat[i]),
+        row, _ = ops.flatten_pytree(
+            jax.tree.map(lambda leaf: leaf[i], stacked), spec)
+        assert row.shape == (n,)
+        np.testing.assert_allclose(np.asarray(row),
                                    np.asarray(d.vec())[:n], rtol=0, atol=1e-6)
 
 

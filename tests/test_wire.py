@@ -3,9 +3,9 @@
 Envelope round-trips through ``store.put``/``get_decoded`` must be
 bit-exact for every wire method x delta/no-delta combination (quantization
 happens at encode; the store/serialization layers may not perturb a single
-bit), the legacy pre-wire ``{"__method__": "int8"}`` envelope must keep
-decoding, and delta envelopes must resolve their base chain through the
-store's decoded cache.
+bit), each method keeps its fidelity and size, delta envelopes must resolve
+their base chain through the store's decoded cache, and a round's int8
+payloads stack for the device in one place (``stack_q8``).
 """
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +14,7 @@ import pytest
 from repro.core import wire
 from repro.core.store import StoreNetwork, StoreNode
 from repro.kernels import ops
+from repro.obs import wall
 
 try:  # property tests run under hypothesis when available (CI installs it);
     # otherwise a fixed seed/length sweep keeps the same invariant covered
@@ -114,18 +115,78 @@ else:
         _roundtrip_any_length(seed, n, combo)
 
 
-def test_legacy_int8_envelope_still_decodes():
-    """Backward decode compatibility: payloads written before the wire layer
-    ({"__method__": "int8", q, scales, n}) decode identically."""
-    vec = _vec(3, 7000, 3.0)
-    q, s, n = ops.quantize(vec)
+# --------------------------------------------------------------------------- #
+# Fidelity and size of each method
+# --------------------------------------------------------------------------- #
+
+def test_int8_roundtrip_error_bound():
+    vec = jnp.concatenate([_vec(0, 5000), _vec(1, 64)])
+    back = wire.encode_vec(vec, "int8").reconstruct()
+    # quantization tiles span leaf boundaries: the bound is the GLOBAL amax
+    amax = float(jnp.max(jnp.abs(vec)))
+    err = float(jnp.max(jnp.abs(back - vec)))
+    assert err <= amax / 127.0 * 0.51 + 1e-5
+
+
+def test_int8_compresses_4x():
+    vec = _vec(0, 200_064)
+    env = wire.encode_vec(vec, "int8")
+    assert env.nbytes() < vec.nbytes / 3.0  # ~4x minus scale overhead
+
+
+def test_topk_delta_keeps_largest():
+    base = _vec(1, 5064)
+    vec = base.at[7].add(100.0)  # one big delta
+    env = wire.encode_vec(vec, "topk-delta", base_vec=base, base_cid="b",
+                          topk_frac=0.001)
+    back = env.reconstruct(base)
+    assert abs(float(back[7] - vec[7])) < 1e-3
+    # untouched coordinates come back as base
+    np.testing.assert_allclose(np.asarray(back[5000:]),
+                               np.asarray(base[5000:]), atol=1e-5)
+
+
+def test_none_passthrough():
+    vec = _vec(0, 5064)
+    env = wire.encode_vec(vec, "none")
+    assert env.method == "raw"
+    np.testing.assert_array_equal(np.asarray(env.reconstruct()),
+                                  np.asarray(vec))
+
+
+# --------------------------------------------------------------------------- #
+# Stacking a round's payloads for the device
+# --------------------------------------------------------------------------- #
+
+def test_stack_q8_stacks_payloads_and_counts_their_bytes_once():
     node = StoreNode("n0")
-    cid = node.put({"__method__": np.asarray("int8"), "q": np.asarray(q),
-                    "scales": np.asarray(s), "n": np.asarray(n)})
-    dm = node.get_decoded(cid, node.wire_decoder())
-    assert dm.is_q8 and dm.n == 7000
-    want = ops.dequantize(q, s, 7000)
-    np.testing.assert_array_equal(np.asarray(dm.vec()), np.asarray(want))
+    decoded = [node.get_decoded(node.put(wire.encode_vec(
+        _vec(i, 7000), "int8").to_store()), node.wire_decoder())
+        for i in range(3)]
+    wall.switch(True)
+    try:
+        before = wall.totals()
+        q, s = wire.stack_q8(decoded)
+        d = wall.diff(before, wall.totals())
+    finally:
+        wall.switch(False)
+    npad = 7000 + (-7000) % ops.QUANT_BLOCK
+    assert q.shape == (3, npad) and q.dtype == np.int8
+    assert s.shape == (3, npad // wire.QT) and s.dtype == np.float32
+    for i, dm in enumerate(decoded):
+        np.testing.assert_array_equal(q[i], dm.q)
+        np.testing.assert_array_equal(s[i], dm.scales)
+    assert d["h2d_bytes"] == q.nbytes + s.nbytes
+
+
+def test_stack_q8_rejects_two_padded_lengths():
+    node = StoreNode("n0")
+    short, long_ = (node.get_decoded(node.put(wire.encode_vec(
+        _vec(i, n), "int8").to_store()), node.wire_decoder())
+        for i, n in enumerate((100, ops.QUANT_BLOCK + 1)))
+    with pytest.raises(ValueError,
+                       match=f"{ops.QUANT_BLOCK}.*{2 * ops.QUANT_BLOCK}"):
+        wire.stack_q8([short, long_])
 
 
 def test_delta_base_chain_resolves_across_peers():
@@ -217,7 +278,7 @@ def test_keyframe_bounds_delta_chain_walk():
 
 def test_grep_gate_method_key_only_in_wire():
     """Acceptance: the '__method__' envelope key appears in exactly one
-    module under src/ — repro/core/wire.py (the legacy-decode shim)."""
+    module under src/ — repro/core/wire.py, the codec."""
     import pathlib
     root = pathlib.Path(__file__).resolve().parent.parent / "src"
     offenders = [p for p in root.rglob("*.py")
